@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import gzip as _gzip
+import os
 import time
 from typing import Callable, Dict, List, Tuple
 
@@ -13,6 +14,30 @@ import numpy as np
 #: a CI-grade "do all benchmarks still execute" check, not a measurement.
 SMOKE = False
 SMOKE_DIVISOR = 32
+
+
+#: The persistent compile cache's place when ``JAX_COMPILATION_CACHE_DIR``
+#: is not set: fixed, so that one checkout's runs find each other's entries.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for an entry point; returns
+    its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is read by JAX itself and this
+    sets no other directory; otherwise the cache is ``COMPILE_CACHE_DIR``.
+    Every compile is cached, however short: the kernels compile in about a
+    second each, under JAX's default threshold.
+    """
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def set_smoke(on: bool = True) -> None:

@@ -64,6 +64,7 @@ def main() -> None:
              " before overwriting it; flag >25%% per-row regressions",
     )
     args = ap.parse_args()
+    common.use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if args.smoke:
         common.set_smoke(True)

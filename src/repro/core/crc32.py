@@ -2,7 +2,7 @@
 
 The paper lists checksum verification as future work (§6); rapidgzip-JAX
 implements it. Each chunk's CRC32 is computed independently on the thread
-pool (``zlib.crc32`` or the Pallas slice-by-8 kernel) and the per-chunk
+pool (``zlib.crc32`` or the Pallas lane kernel) and the per-chunk
 values are merged sequentially with the O(log n) zlib ``crc32_combine``
 matrix trick — the merge touches 32-bit state only, so the sequential part
 of checksumming is negligible (same Amdahl argument as window propagation).
@@ -10,7 +10,10 @@ of checksumming is negligible (same Amdahl argument as window propagation).
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+import functools
+from typing import List, Optional, Sequence, Tuple
+
+import numpy as np
 
 _POLY = 0xEDB88320
 
@@ -30,31 +33,58 @@ def _gf2_matrix_square(mat: Sequence[int]) -> List[int]:
     return [_gf2_matrix_times(mat, mat[i]) for i in range(32)]
 
 
+@functools.lru_cache(maxsize=256)
+def _zeros_operator(nbytes: int) -> Tuple[int, ...]:
+    """GF(2) operator (32 column images) that appends ``nbytes`` zero bytes.
+
+    Built by square-and-multiply from the one-zero-bit operator, as zlib's
+    ``crc32_combine`` does, and cached: serving reads combine a handful of
+    distinct lengths (member sizes, lane lengths) over and over.
+    """
+    op: List[int] = [_POLY] + [1 << (i - 1) for i in range(1, 32)]  # one bit
+    for _ in range(3):
+        op = _gf2_matrix_square(op)  # 2, 4, then 8 bits: one zero byte
+    acc: Optional[List[int]] = None
+    n = nbytes
+    while n:
+        if n & 1:
+            acc = op if acc is None else [_gf2_matrix_times(op, c) for c in acc]
+        n >>= 1
+        if n:
+            op = _gf2_matrix_square(op)
+    return tuple(acc)
+
+
 def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
     """CRC32 of the concatenation of two blocks (zlib's crc32_combine)."""
     if len2 <= 0:
         return crc1 & 0xFFFFFFFF
-    # Operator for one zero bit.
-    odd = [_POLY] + [1 << (i - 1) for i in range(1, 32)]
-    even = _gf2_matrix_square(odd)  # two zero bits
-    odd = _gf2_matrix_square(even)  # four zero bits
-    crc1 &= 0xFFFFFFFF
-    crc2 &= 0xFFFFFFFF
-    # Apply len2 zero bytes to crc1, alternating the squared operators.
-    do_odd = False
-    n = len2
-    while n:
-        if do_odd:
-            odd = _gf2_matrix_square(even)
-            if n & 1:
-                crc1 = _gf2_matrix_times(odd, crc1)
-        else:
-            even = _gf2_matrix_square(odd)
-            if n & 1:
-                crc1 = _gf2_matrix_times(even, crc1)
-        do_odd = not do_odd
-        n >>= 1
-    return (crc1 ^ crc2) & 0xFFFFFFFF
+    shifted = _gf2_matrix_times(_zeros_operator(len2), crc1 & 0xFFFFFFFF)
+    return (shifted ^ crc2) & 0xFFFFFFFF
+
+
+def combine_lanes(crcs: np.ndarray, lane_len: int) -> np.ndarray:
+    """Fold each row of equal-length lane CRCs left to right, vectorized.
+
+    ``crcs`` is ``(rows, lanes)`` with ``lanes`` a power of two and every
+    lane ``lane_len`` bytes long; returns ``(rows,)`` uint32. A pairwise
+    tree: level ``k`` merges neighbours of ``lane_len * 2**k`` bytes with
+    one cached operator, 32 NumPy passes per level. A lane holding 0 folds
+    in as the CRC of an empty string, so callers right-align short rows.
+    """
+    arr = np.asarray(crcs, np.uint32)
+    if arr.shape[1] & (arr.shape[1] - 1):
+        raise ValueError("lane count must be a power of two")
+    n = lane_len
+    while arr.shape[1] > 1:
+        op = _zeros_operator(n)
+        left, right = arr[:, 0::2], arr[:, 1::2]
+        shifted = np.zeros_like(left)
+        for i in range(32):
+            shifted ^= ((left >> np.uint32(i)) & np.uint32(1)) * np.uint32(op[i])
+        arr = shifted ^ right
+        n *= 2
+    return arr[:, 0]
 
 
 class RunningCRC:

@@ -83,8 +83,6 @@ def pipeline_apply(
         jax.tree.map(lambda _: P(axis), stage_params),
         P(),
     )
-    from .sharding import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )(stage_params, x)

@@ -25,24 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 exposes it top-level with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with the same semantics under
-    ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class ShardingRules:
     rules: Dict[str, MeshAxes]

@@ -1,12 +1,14 @@
-"""Pallas TPU kernels for the paper's compute hot spots.
+"""Device kernels for the paper's compute hot spots.
 
 Each kernel ships three layers (repo convention):
-  * ``<name>.py`` — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling.
-  * ``ops.py``    — jit'd wrappers (padding/reshape/dtype glue).
-  * ``ref.py``    — pure-jnp oracle for allclose validation.
+  * ``<name>.py`` — the device computation: ``pl.pallas_call`` with
+    explicit BlockSpec VMEM tiling (CRC32, block-finder precheck), or a
+    jitted XLA gather where Mosaic has no lowering (marker replacement).
+  * ``ops.py``    — per-call host wrappers (padding/reshape/dtype glue).
+  * ``ref.py``    — pure-jnp oracle for bit-exact validation.
 
-This container is CPU-only: kernels validate with ``interpret=True`` (kernel
-bodies execute in Python); TPU v5e is the compile target.
+Pallas kernels are interpreted on the CPU backend (the tests) and compiled
+on a TPU (``ops.interpret_on``); TPU v5e is the target.
 """
 
 from .engine import DeviceDecodeEngine, EngineClosedError

@@ -32,12 +32,12 @@ Layout and policy:
     CPU path inline (``core.markers`` / ``zlib.crc32``) and are counted as
     ``fallbacks``: interactive p99 never pays the batching latency tax. The
     threshold is derived from the committed ``BENCH_kernels.json`` batched
-    dispatch sweep (see ``derive_crossover``); on hosts where the device
-    never wins (e.g. interpret mode on CPU) the derived crossover is None
-    and *everything* falls back — the engine stays on the hot path only for
-    accounting, costing one branch per request.
-  * **Degradation** — when jax is unavailable the engine constructs fine,
-    reports ``available=False``, and routes every request to the CPU path.
+    dispatch sweep (see ``derive_crossover``); where the device never wins
+    on that artifact the derived crossover is None and *everything* falls
+    back unless ``force_device`` is set.
+  * **One device** — every array the engine dispatches is placed on
+    ``self.device`` (the first device JAX reports), and the kernels are
+    interpreted only when that device is the CPU.
 
 Bit-identity: the device path computes the same gather/CRC as the host path
 (int32 tables hold byte values; CRCs are exact), so results are
@@ -57,31 +57,28 @@ from collections import OrderedDict, deque
 from concurrent.futures import Future
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
-from ..core.crc32 import combine_parts
 from ..core.markers import replace_markers as _cpu_replace_markers
 from ..obs import trace as _obs_trace
-
-try:  # pragma: no cover - exercised via available=False paths in tests
-    import jax.numpy as jnp
-
-    from .crc32 import N_SEGMENTS, crc32_segments_batched, make_crc_table
-    from .marker_replace import (
-        TABLE_SIZE,
-        TILE,
-        TILE_COLS,
-        TILE_ROWS,
-        marker_replace_tiles_multi,
-    )
-    from .ops import INTERPRET
-    from .ref import make_replacement_table
-
-    _HAVE_JAX = True
-except Exception:  # noqa: BLE001 - any import failure means "no device"
-    _HAVE_JAX = False
-    INTERPRET = True
-    TILE, TILE_ROWS, TILE_COLS, N_SEGMENTS = 8192, 8, 1024, 1024
+from .crc32 import (
+    SEG_COLS,
+    SEG_ROWS,
+    crc32_segments_batched,
+    finish_crcs,
+    lane_words,
+    pack_lanes,
+)
+from .marker_replace import (
+    TABLE_SIZE,
+    TILE,
+    TILE_COLS,
+    TILE_ROWS,
+    marker_replace_tiles_multi,
+)
+from .ops import interpret_on
+from .ref import make_replacement_table
 
 _TILE_BYTES = TILE  # one symbol resolves to one output byte
 
@@ -215,7 +212,6 @@ class DeviceDecodeEngine:
         max_delay_s: float = 0.002,
         crossover: Union[str, None, Dict[str, Optional[int]]] = "auto",
         force_device: bool = False,
-        interpret: Optional[bool] = None,
         artifact_root: Optional[str] = None,
     ):
         self.max_batch_tiles = max(1, max_batch_tiles)
@@ -224,8 +220,8 @@ class DeviceDecodeEngine:
         self.max_crc_requests = max(1, max_crc_requests)
         self.max_delay_s = max(0.0, max_delay_s)
         self.force_device = force_device
-        self.interpret = INTERPRET if interpret is None else interpret
-        self.available = _HAVE_JAX
+        self.device = jax.devices()[0]
+        self.interpret = interpret_on(self.device)
         if crossover == "auto":
             self.crossover = load_crossover(artifact_root)
         elif crossover is None:
@@ -251,11 +247,10 @@ class DeviceDecodeEngine:
         self._stack_cache: "OrderedDict[Tuple, Any]" = OrderedDict()
         self._stack_cache_cap = 8
         # Double-buffered host staging: two numpy buffers per bucket shape,
-        # alternating between consecutive dispatches so packing batch N+1
-        # never scribbles over memory the in-flight transfer of batch N may
-        # still be reading (pinned-buffer discipline on real hardware).
+        # alternating between consecutive dispatches of that shape so packing
+        # batch N+1 never scribbles over memory the in-flight transfer of
+        # batch N may still be reading (at most one batch is in flight).
         self._staging: Dict[Tuple, List[np.ndarray]] = {}
-        self._staging_phase = 0
 
         # Counters (mutated under self._cond).
         self._requests = {"replace": 0, "crc": 0}
@@ -269,19 +264,17 @@ class DeviceDecodeEngine:
         self._max_queue_depth = 0
         self._errors = 0
 
-        self._worker: Optional[threading.Thread] = None
-        if self.available:
-            self._worker = threading.Thread(
-                target=self._worker_loop, name="device-decode-engine", daemon=True
-            )
-            self._worker.start()
+        self._worker = threading.Thread(
+            target=self._worker_loop, name="device-decode-engine", daemon=True
+        )
+        self._worker.start()
 
     # ------------------------------------------------------------------
     # routing policy
     # ------------------------------------------------------------------
 
     def _route_device(self, kind: str, nbytes: int) -> bool:
-        if not self.available or self._closed:
+        if self._closed:
             return False
         if self.force_device:
             return True
@@ -299,19 +292,13 @@ class DeviceDecodeEngine:
     def submit_replace(self, symbols: np.ndarray, window: Optional[bytes]) -> Future:
         """Queue a marker-resolution request; resolves to a uint8 array.
 
-        Tiny/degenerate requests resolve immediately without touching the
-        queue; when the device is unavailable the work happens inline on the
-        caller's thread (counted as a fallback) so the future contract holds
-        everywhere.
+        Already-resolved (uint8) and empty requests resolve immediately
+        without touching the queue.
         """
         self._count(self._requests, "replace")
         fut: Future = Future()
         if symbols.dtype == np.uint8 or symbols.shape[0] == 0:
             fut.set_result(np.asarray(symbols, dtype=np.uint8))
-            return fut
-        if not self.available:
-            self._count(self._fallbacks, "replace")
-            fut.set_result(_cpu_replace_markers(symbols, window))
             return fut
         req = _Request("replace", symbols=symbols, window=window)
         self._enqueue(self._rq, req)
@@ -324,10 +311,6 @@ class DeviceDecodeEngine:
         fut: Future = Future()
         if len(data) == 0:
             fut.set_result(0)
-            return fut
-        if not self.available:
-            self._count(self._fallbacks, "crc")
-            fut.set_result(_zlib.crc32(data) & 0xFFFFFFFF)
             return fut
         req = _Request("crc", data=data)
         self._enqueue(self._cq, req)
@@ -458,9 +441,7 @@ class DeviceDecodeEngine:
             except BaseException as exc:  # noqa: BLE001 - fail the batch, keep serving
                 with self._cond:
                     self._errors += 1
-                for req in rep + crc:
-                    if not req.future.done():
-                        req.future.set_exception(exc)
+                _fail(rep + crc, exc)
                 continue
             # Pipeline: resolve the *previous* dispatch only after launching
             # this one — readback of batch N overlaps device work of N+1.
@@ -480,12 +461,19 @@ class DeviceDecodeEngine:
             self._resolve_safely(pending)
 
     def _resolve_safely(self, launched) -> None:
-        for resolve in launched:
+        """Read back launched dispatches; a failure fails its requests.
+
+        Device errors surface here, at readback, not at launch. Every
+        future of a failed dispatch gets the exception: a reader blocked on
+        one must never wait for a result that will not come.
+        """
+        for resolve, reqs in launched:
             try:
                 resolve()
-            except BaseException:  # noqa: BLE001 - resolve() fails its own futures
+            except BaseException as exc:  # noqa: BLE001 - fail the batch, keep serving
                 with self._cond:
                     self._errors += 1
+                _fail(reqs, exc)
 
     # -- marker replacement dispatch ------------------------------------
 
@@ -505,7 +493,8 @@ class DeviceDecodeEngine:
         if bufs is None:
             bufs = [np.zeros(shape, np.int32), np.zeros(shape, np.int32)]
             self._staging[key] = bufs
-        return bufs[self._staging_phase & 1]
+        bufs.reverse()  # alternate: the other one may still be in flight
+        return bufs[0]
 
     def _table_stack(self, keys: Tuple[bytes, ...]) -> Any:
         """Device-resident (n_tables, TABLE_SIZE) stack for a window set.
@@ -523,15 +512,18 @@ class DeviceDecodeEngine:
         tab_stack = np.zeros((n_tables, TABLE_SIZE), np.int32)
         for i in range(n_tables):
             tab_stack[i] = self._replacement_table(keys[min(i, len(keys) - 1)])
-        stack = jnp.asarray(tab_stack)
+        stack = jax.device_put(tab_stack, self.device)
         self._stack_cache[cache_key] = stack
         if len(self._stack_cache) > self._stack_cache_cap:
             self._stack_cache.popitem(last=False)
         return stack
 
     def _dispatch_replace(self, reqs: List[_Request]):
-        """Pack, upload, and launch one marker batch; returns resolve()."""
-        self._staging_phase += 1
+        """Pack, upload, and launch one marker batch.
+
+        Returns ``(resolve, reqs)``: ``resolve()`` reads the result back and
+        completes the requests' futures.
+        """
         # Dedupe windows into a table stack; selector per tile.
         table_ids: Dict[bytes, int] = {}
         total_tiles = sum(r.tiles for r in reqs)
@@ -550,7 +542,10 @@ class DeviceDecodeEngine:
             )
             sym_flat = stage.reshape(-1)
         else:
-            sym_flat = np.zeros(total_tiles * TILE, np.int32)
+            # Oversized: every slab is a view of one fresh buffer, so no
+            # slab's upload can be overwritten by the next slab's packing.
+            n_slabs = -(-total_tiles // self.max_batch_tiles)
+            sym_flat = np.zeros(n_slabs * self.max_batch_tiles * TILE, np.int32)
         pos = 0
         for req in reqs:
             key = bytes(req.window or b"")
@@ -567,27 +562,22 @@ class DeviceDecodeEngine:
         tab_dev = self._table_stack(tuple(table_ids))
 
         # Slab the packed tiles: oversized single requests span multiple
-        # kernel launches, everything else fits one. Bucketed shapes keep
-        # the set of compiled kernels small and cached.
+        # dispatches, everything else fits one. Bucketed shapes keep the set
+        # of compiled programs small and cached.
         outs: List[Tuple[Any, int]] = []
         slabs = 0
         for s0 in range(0, total_tiles, self.max_batch_tiles):
             n = min(self.max_batch_tiles, total_tiles - s0)
             bucket = _pow2_at_least(n, self.max_batch_tiles)
-            if single:
-                stage_slab = stage
-            else:
-                stage_slab = self._staging_buffer(
-                    ("rep", bucket), (bucket, TILE_ROWS, TILE_COLS)
-                )
-                stage_slab.reshape(-1)[: n * TILE] = (
-                    sym_flat[s0 * TILE : (s0 + n) * TILE]
-                )
+            slab = sym_flat[s0 * TILE : (s0 + bucket) * TILE]
             tids = np.zeros(bucket, np.int32)
             tids[:n] = tid_flat[s0 : s0 + n]
             out = marker_replace_tiles_multi(
-                jnp.asarray(stage_slab), tab_dev, jnp.asarray(tids),
-                interpret=self.interpret,
+                jax.device_put(
+                    slab.reshape(bucket, TILE_ROWS, TILE_COLS), self.device
+                ),
+                tab_dev,
+                jax.device_put(tids, self.device),
             )
             outs.append((out, n))
             slabs += 1
@@ -607,54 +597,36 @@ class DeviceDecodeEngine:
                         flat_out[off : off + n].astype(np.uint8)
                     )
 
-        return resolve
+        return resolve, reqs
 
     # -- CRC dispatch ----------------------------------------------------
 
     def _dispatch_crc(self, reqs: List[_Request]):
-        """Pack many byte streams into one (B, 8, 128, seg_len) dispatch."""
-        self._staging_phase += 1
-        seg_len = _pow2_at_least(
-            max(1, max(-(-r.nbytes // N_SEGMENTS) for r in reqs))
-        )
-        batch = _pow2_at_least(len(reqs))
-        from .crc32 import SEG_COLS, SEG_ROWS  # local: shapes only
+        """Pack many byte streams into one (B, seg_words, 8, 128) dispatch.
 
+        Returns ``(resolve, reqs)`` like ``_dispatch_replace``.
+        """
+        seg_words = lane_words(max(r.nbytes for r in reqs))
+        batch = _pow2_at_least(len(reqs))
         stage = self._staging_buffer(
-            ("crc", batch, seg_len), (batch, SEG_ROWS, SEG_COLS, seg_len)
+            ("crc", batch, seg_words), (batch, seg_words, SEG_ROWS, SEG_COLS)
         )
-        stage.fill(0)
-        fulls: List[int] = []
-        for bi, req in enumerate(reqs):
-            full = req.nbytes // seg_len
-            fulls.append(full)
-            if full:
-                lanes = stage[bi].reshape(N_SEGMENTS, seg_len)
-                lanes[:full] = np.frombuffer(
-                    req.data, np.uint8, count=full * seg_len
-                ).reshape(full, seg_len)
+        for row, req in zip(stage, reqs):
+            pack_lanes(row, req.data)
         out = crc32_segments_batched(
-            jnp.asarray(stage), make_crc_table(), interpret=self.interpret
+            jax.device_put(stage, self.device), interpret=self.interpret
         )
         with self._cond:
             self._dispatches += 1
             self._crc_bytes += sum(r.nbytes for r in reqs)
 
         def resolve() -> None:
-            crcs = np.asarray(out).astype(np.uint32)
-            for bi, req in enumerate(reqs):
-                lanes = crcs[bi].reshape(-1)
-                full = fulls[bi]
-                parts = [(int(lanes[s]), seg_len) for s in range(full)]
-                rem = req.nbytes - full * seg_len
-                if rem:
-                    parts.append(
-                        (_zlib.crc32(req.data[full * seg_len :]) & 0xFFFFFFFF, rem)
-                    )
+            crcs = finish_crcs(np.asarray(out), [r.data for r in reqs], seg_words)
+            for req, crc in zip(reqs, crcs):
                 if not req.future.done():
-                    req.future.set_result(combine_parts(parts))
+                    req.future.set_result(crc)
 
-        return resolve
+        return resolve, reqs
 
     # ------------------------------------------------------------------
     # lifecycle & telemetry
@@ -695,7 +667,7 @@ class DeviceDecodeEngine:
         with self._cond:
             tiles_total = self._tiles_dispatched + self._tiles_padded
             return {
-                "available": self.available,
+                "platform": self.device.platform,
                 "interpret": self.interpret,
                 "force_device": self.force_device,
                 "crossover_bytes": dict(self.crossover),
@@ -715,6 +687,12 @@ class DeviceDecodeEngine:
                 "errors": self._errors,
                 "closed": self._closed,
             }
+
+
+def _fail(reqs: List[_Request], exc: BaseException) -> None:
+    for req in reqs:
+        if not req.future.done():
+            req.future.set_exception(exc)
 
 
 def _as_bytes(data) -> bytes:

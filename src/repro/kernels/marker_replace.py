@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: stage-2 marker replacement (paper §2.2 step 3, Table 2).
+"""Stage-2 marker replacement on the device (paper §2.2 step 3, Table 2).
 
 Marker replacement is the data-parallel half of two-stage decompression:
 
@@ -6,107 +6,40 @@ Marker replacement is the data-parallel half of two-stage decompression:
     out[i] = window[sym[i] - 256]        otherwise         (marker)
 
 which collapses into a single gather through a 33 024-entry replacement
-table (``[0..255] ++ window``). On TPU the table (132 KiB as int32) is
-pinned whole in VMEM while symbol tiles stream HBM→VMEM; the gather runs on
-the VPU at memory bandwidth — the TPU-native analogue of the paper's
-L1-resident window on CPU.
+table (``[0..255] ++ window``).
 
-Tiling: symbols are processed in (8, 1024) int32 tiles (8×128-lane VREG
-granularity); the grid walks the flattened symbol stream.
+The gather is one jitted XLA gather, not a Pallas kernel: Mosaic lowers no
+general gather from a VMEM table (only gathers within one vreg), and the
+table is 33 024 entries. XLA's TPU gather reads the table stack from HBM.
+
+Tiling: symbols arrive in (8, 1024) int32 tiles (whole (8, 128) vregs); a
+dispatch carries tiles from many chunks plus a per-tile table selector.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from .ref import TABLE_SIZE
 
-# One tile = SUBLANES x LANES*4 elements; int32 VREGs are (8, 128).
+# One tile = SUBLANES x LANES*8 elements; int32 VREGs are (8, 128).
 TILE_ROWS = 8
 TILE_COLS = 1024
 TILE = TILE_ROWS * TILE_COLS
 
 
-def _marker_replace_kernel(syms_ref, table_ref, out_ref):
-    """out = table[syms] — table resident in VMEM, symbols tiled."""
-    syms = syms_ref[...]
-    table = table_ref[...]
-    out_ref[...] = table[syms]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def marker_replace_tiles(syms: jax.Array, table: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """Gather-replace over tiled int32 symbols.
-
-    syms:  (n_tiles, TILE_ROWS, TILE_COLS) int32 (padded, values < TABLE_SIZE)
-    table: (TABLE_SIZE,) int32 replacement table
-    returns same shape int32 with markers resolved to byte values.
-    """
-    n_tiles = syms.shape[0]
-    return pl.pallas_call(
-        _marker_replace_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda i: (i, 0, 0)),
-            pl.BlockSpec((TABLE_SIZE,), lambda i: (0,)),  # whole table in VMEM
-        ],
-        out_specs=pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(syms.shape, jnp.int32),
-        interpret=interpret,
-    )(syms, table)
-
-
-def _marker_replace_multi_kernel(tids_ref, syms_ref, tables_ref, out_ref):
-    """out = tables[tid][syms] — one table per tile, selected dynamically.
-
-    The batched-engine variant: a dispatch carries tiles from many chunks
-    (each chunk resolved against its own window), so the replacement table
-    becomes a small VMEM-resident stack of tables plus a per-tile int32
-    selector. The gather itself is unchanged; only the table load gains one
-    dynamic index (a VMEM-local dynamic slice, free on the VPU).
-    """
-    tid = tids_ref[0]
-    syms = syms_ref[...]
-    table = tables_ref[tid, :]
-    out_ref[...] = table[syms]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def marker_replace_tiles_multi(
-    syms: jax.Array,
-    tables: jax.Array,
-    tile_tables: jax.Array,
-    *,
-    interpret: bool = False,
+    syms: jax.Array, tables: jax.Array, tile_tables: jax.Array
 ) -> jax.Array:
     """Gather-replace over tiles drawn from many chunks/windows in one call.
 
-    syms:        (n_tiles, TILE_ROWS, TILE_COLS) int32 (padded)
+    syms:        (n_tiles, TILE_ROWS, TILE_COLS) int32 (padded, < TABLE_SIZE)
     tables:      (n_tables, TABLE_SIZE) int32 — one replacement table per
-                 distinct window in the batch (all resident in VMEM: 132 KiB
-                 each, so a 16-window batch is ~2 MiB, well inside v5e VMEM)
+                 distinct window in the batch
     tile_tables: (n_tiles,) int32 — table index for each tile
     returns syms-shaped int32 with markers resolved.
-
-    On real TPU hardware the per-tile selector would ride scalar prefetch
-    (``PrefetchScalarGridSpec``) so the index is known before the body runs;
-    interpret mode (this container) takes it as a 1-element block.
     """
-    n_tiles = syms.shape[0]
-    n_tables = tables.shape[0]
-    return pl.pallas_call(
-        _marker_replace_multi_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda i: (i, 0, 0)),
-            pl.BlockSpec((n_tables, TABLE_SIZE), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(syms.shape, jnp.int32),
-        interpret=interpret,
-    )(tile_tables, syms, tables)
+    with jax.named_scope("marker_replace_tiles_multi"):
+        flat = tile_tables[:, None, None] * TABLE_SIZE + syms
+        return tables.reshape(-1).at[flat].get(mode="clip")

@@ -1,69 +1,58 @@
-"""jit'd high-level wrappers around the Pallas kernels.
+"""Host-side wrappers around the device kernels.
 
-These are the entry points the rest of the system uses: they pad/reshape
-host data into kernel tiling, dispatch (interpret=True on CPU — TPU v5e is
-the compile target), and restore shapes/dtypes.
+These are the per-call entry points outside the serving engine: they pad
+and reshape host data into kernel tiling, dispatch on the default device,
+and restore shapes/dtypes. For cross-chunk batching on the serving hot
+path, see ``kernels/engine.py``; both use the same kernels.
 
-Constant tables are cached at module level: the CRC byte LUT (one device
-transfer per process, via ``make_crc_table``'s own memo) and the
-empty-window replacement table (the common case for the first chunk of a
-stream). The jitted dispatch functions themselves are module-level
-``jax.jit``s, so traces are shared per shape bucket across calls — per-call
-work is reduced to padding + the dispatch itself. For cross-chunk batching
-on the serving hot path, see ``kernels/engine.py``.
+Whether a Pallas kernel is interpreted is decided per dispatch from the
+device it runs on (``interpret_on``): interpreted on the CPU backend, which
+is how the tests run, and compiled on a TPU — never interpreted there.
 """
 
 from __future__ import annotations
 
-import zlib as _zlib
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from ..core.crc32 import combine_parts
-from .crc32 import N_SEGMENTS, SEG_COLS, SEG_ROWS, crc32_segments, make_crc_table
-from .marker_replace import TILE, TILE_COLS, TILE_ROWS, marker_replace_tiles
-from .precode_check import BLOCK, HALO, precode_check_blocks
+from .crc32 import (
+    SEG_COLS,
+    SEG_ROWS,
+    crc32_segments_batched,
+    finish_crcs,
+    lane_words,
+    pack_lanes,
+)
+from .marker_replace import TILE, TILE_COLS, TILE_ROWS, marker_replace_tiles_multi
+from .precode_check import BLOCK, HALO, ROWS, precode_check_blocks
 from .ref import make_replacement_table
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-#: interpret=True executes kernel bodies in Python on CPU — the validation
-#: mode for this container; on real TPU hardware the same calls compile.
-INTERPRET = not _ON_TPU
 
-_EMPTY_WINDOW_TABLE: Optional[jax.Array] = None
-
-
-def replacement_table_device(window: Optional[bytes]) -> jax.Array:
-    """Device-resident int32 replacement table for ``window``.
-
-    The empty-window table (every marker resolves to 0 — the first chunk of
-    any stream) is a constant and cached; real windows are content-dependent
-    and built per call.
-    """
-    global _EMPTY_WINDOW_TABLE
-    if not window:
-        if _EMPTY_WINDOW_TABLE is None:
-            _EMPTY_WINDOW_TABLE = jnp.asarray(
-                make_replacement_table(np.empty(0, np.uint8))
-            )
-        return _EMPTY_WINDOW_TABLE
-    return jnp.asarray(make_replacement_table(np.frombuffer(window, np.uint8)))
+def interpret_on(device: jax.Device) -> bool:
+    """Pallas interpret mode: only on the CPU backend, never on a TPU."""
+    return device.platform == "cpu"
 
 
 # -- marker replacement -------------------------------------------------------
 
 def marker_replace(symbols: np.ndarray, window: Optional[bytes]) -> np.ndarray:
-    """Resolve a uint16 marker stream to bytes via the Pallas kernel."""
+    """Resolve a uint16 marker stream to bytes on the device.
+
+    One chunk is a batch of its tiles against a stack of one table.
+    """
+    device = jax.devices()[0]
     n = symbols.shape[0]
-    table = replacement_table_device(window)
+    table = make_replacement_table(np.frombuffer(window or b"", np.uint8))
     n_tiles = max(1, -(-n // TILE))
     padded = np.zeros(n_tiles * TILE, dtype=np.int32)
-    padded[:n] = symbols.astype(np.int32)
-    tiles = jnp.asarray(padded.reshape(n_tiles, TILE_ROWS, TILE_COLS))
-    out = marker_replace_tiles(tiles, table, interpret=INTERPRET)
+    padded[:n] = symbols
+    out = marker_replace_tiles_multi(
+        jax.device_put(padded.reshape(n_tiles, TILE_ROWS, TILE_COLS), device),
+        jax.device_put(table[None], device),
+        jax.device_put(np.zeros(n_tiles, np.int32), device),
+    )
     return np.asarray(out).reshape(-1)[:n].astype(np.uint8)
 
 
@@ -90,39 +79,30 @@ def precode_candidates(data: bytes, start_bit: int = 0, end_bit: Optional[int] =
     bits = np.unpackbits(raw, bitorder="little").astype(np.int32)
     rel = start_bit - first_byte * 8
 
-    n_blocks = max(1, -(-n // BLOCK))
-    padded = np.zeros((n_blocks + 1) * BLOCK, dtype=np.int32)
+    # Rows cover every offset plus its halo; bits past the last row read 0.
+    n_rows = -(-(n + HALO) // BLOCK)
+    n_rows = -(-n_rows // ROWS) * ROWS
+    padded = np.zeros(n_rows * BLOCK, dtype=np.int32)
     usable = min(bits.shape[0] - rel, padded.shape[0])
     padded[:usable] = bits[rel : rel + usable]
-    blocks = jnp.asarray(padded.reshape(n_blocks + 1, BLOCK))
-    mask = np.asarray(precode_check_blocks(blocks, interpret=INTERPRET)).reshape(-1)[:n]
+    device = jax.devices()[0]
+    blocks = jax.device_put(padded.reshape(n_rows, BLOCK), device)
+    mask = precode_check_blocks(blocks, interpret=interpret_on(device))
+    mask = np.asarray(mask).reshape(-1)[:n]
     return np.nonzero(mask)[0].astype(np.int64) + start_bit
 
 
 # -- crc32 --------------------------------------------------------------------
 
 def crc32_parallel(data: bytes) -> int:
-    """CRC32 of ``data`` via N_SEGMENTS parallel lanes + GF(2) combine."""
-    n = len(data)
-    if n == 0:
+    """CRC32 of ``data`` via 1024 parallel lanes + GF(2) combine."""
+    if not data:
         return 0
-    seg_len = max(1, -(-n // N_SEGMENTS))
-    padded = np.zeros(N_SEGMENTS * seg_len, dtype=np.uint8)
-    padded[:n] = np.frombuffer(data, np.uint8)
-    tiles = jnp.asarray(
-        padded.reshape(SEG_ROWS, SEG_COLS, seg_len).astype(np.int32)
+    device = jax.devices()[0]
+    seg_words = lane_words(len(data))
+    stage = np.zeros((1, seg_words, SEG_ROWS, SEG_COLS), dtype=np.int32)
+    pack_lanes(stage[0], data)
+    lanes = crc32_segments_batched(
+        jax.device_put(stage, device), interpret=interpret_on(device)
     )
-    crcs = np.asarray(crc32_segments(tiles, make_crc_table(), interpret=INTERPRET)).astype(np.uint32)
-    # Combine per-segment CRCs; the tail segment may be short — zero padding
-    # inside a segment changes its CRC, so true lengths are honored by
-    # recomputing the last (partial) segment's CRC on the host.
-    parts = []
-    flat = crcs.reshape(-1)
-    full_segments = n // seg_len
-    for s in range(full_segments):
-        parts.append((int(flat[s]), seg_len))
-    rem = n - full_segments * seg_len
-    if rem:
-        tail = data[full_segments * seg_len :]
-        parts.append((_zlib.crc32(tail) & 0xFFFFFFFF, rem))
-    return combine_parts(parts)
+    return finish_crcs(np.asarray(lanes), [data], seg_words)[0]

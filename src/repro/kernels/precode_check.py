@@ -16,10 +16,13 @@ survive (≈0.05 % on random data, Table 1) are confirmed on the host with the
 full strict header parse (steps 5–7) — the same split as the production
 finder in ``core/block_finder.py``.
 
-Input is the LSB-first bit plane as int32 0/1. Each tile needs a 74-bit
-halo, provided by passing the *neighbor block* as a second view of the same
-operand (standard Pallas halo pattern: two in_specs over one array with
-shifted index maps).
+Input is the LSB-first bit plane as int32 0/1, laid out in rows of ``BLOCK``
+bits; a grid step takes ``ROWS`` rows (whole vregs). Offset ``j`` of a row
+needs the 74 bits after it, which run on into the next row: the next row of
+the same block for rows 0-6, the next block's first row for row 7 (a second
+view of the same operand, shifted by one block). Bit ``j + k`` of every
+offset is a lane rotation by ``k`` of the row and of its continuation,
+merged at the wrap point.
 """
 
 from __future__ import annotations
@@ -29,65 +32,83 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: bits of header probed beyond an offset: 17 header bits + 19*3 precode bits
 HALO = 74
 
-BLOCK = 2048  # offsets checked per grid step (>= HALO so one neighbor suffices)
+BLOCK = 2048  # offsets per row (>= HALO so one continuation row suffices)
+ROWS = 8  # rows per grid step: (8, 2048) int32 is 16 whole vregs
 
 
-def _field(bits, at: int, width: int, n: int):
-    """value[i] = LSB-first ``width``-bit field at offset i+at (vectorized)."""
-    out = jax.lax.dynamic_slice_in_dim(bits, at, n)
-    for j in range(1, width):
-        out = out | (jax.lax.dynamic_slice_in_dim(bits, at + j, n) << j)
-    return out
+def _precode_check_kernel(bits_ref, next_ref, out_ref):
+    cur = bits_ref[...]
+    last = pl.program_id(0) == pl.num_programs(0) - 1
+    nxt = jnp.where(last, 0, next_ref[...])  # past the end reads zeros
+    row = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 1)
+    # cont[r] = the row that follows row r in the bit stream.
+    cont = jnp.where(
+        row == ROWS - 1,
+        pltpu.roll(nxt, ROWS - 1, 0),
+        pltpu.roll(cur, ROWS - 1, 0),
+    )
 
+    def bit(k: int):
+        """Bit at offset + k, for every offset of the block."""
+        if k == 0:
+            return cur
+        return jnp.where(
+            lane < BLOCK - k,
+            pltpu.roll(cur, BLOCK - k, 1),
+            pltpu.roll(cont, BLOCK - k, 1),
+        )
 
-def _precode_check_kernel(bits_ref, halo_ref, out_ref):
-    n = out_ref.shape[-1]
-    bits = jnp.concatenate([bits_ref[0], halo_ref[0][:HALO]], axis=-1)
+    def field(at: int, width: int):
+        out = bit(at)
+        for j in range(1, width):
+            out = out | (bit(at + j) << j)
+        return out
 
-    b0 = jax.lax.dynamic_slice_in_dim(bits, 0, n)
-    b1 = jax.lax.dynamic_slice_in_dim(bits, 1, n)
-    b2 = jax.lax.dynamic_slice_in_dim(bits, 2, n)
-    ok = (b0 == 0) & (b1 == 0) & (b2 == 1)  # (1) + (2)
-
-    hlit = _field(bits, 3, 5, n)
-    ok &= hlit < 30  # (3)
-
-    hclen = _field(bits, 13, 4, n)
-    n_codes = hclen + 4
+    ok = (bit(0) == 0) & (bit(1) == 0) & (bit(2) == 1)  # (1) + (2)
+    ok &= field(3, 5) < 30  # (3)
+    n_codes = field(13, 4) + 4
 
     # (4) Kraft completeness over the (up to 19) 3-bit precode code lengths.
-    kraft = jnp.zeros((n,), jnp.int32)
+    kraft = jnp.zeros(cur.shape, jnp.int32)
     for k in range(19):
-        cl = _field(bits, 17 + 3 * k, 3, n)
+        cl = field(17 + 3 * k, 3)
         active = (k < n_codes) & (cl > 0)
         term = jax.lax.shift_right_logical(jnp.int32(128), cl)
         kraft = kraft + jnp.where(active, term, 0)
     ok &= kraft == 128
 
-    out_ref[0] = ok.astype(jnp.int32)
+    out_ref[...] = ok.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def precode_check_blocks(bits: jax.Array, *, interpret: bool = False) -> jax.Array:
     """Candidate mask for every bit offset.
 
-    bits: (n_blocks + 1, BLOCK) int32 0/1 bit plane — the final block is a
-          zero-padded sentinel so the last real block has a halo neighbor.
-    returns (n_blocks, BLOCK) int32 mask (1 = candidate for steps 5-7).
+    bits: (n_rows, BLOCK) int32 0/1 bit plane, ``n_rows`` a multiple of
+          ``ROWS``; bits past the last row read as zero.
+    returns (n_rows, BLOCK) int32 mask (1 = candidate for steps 5-7).
     """
-    n_blocks = bits.shape[0] - 1
+    n_rows = bits.shape[0]
+    if n_rows % ROWS:
+        raise ValueError("row count must be a multiple of %d" % ROWS)
+    n_blocks = n_rows // ROWS
     return pl.pallas_call(
         _precode_check_kernel,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i + 1, 0)),  # halo neighbor
+            pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
+            pl.BlockSpec(
+                (ROWS, BLOCK), lambda i: (jnp.minimum(i + 1, n_blocks - 1), 0)
+            ),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.int32),
+        out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_rows, BLOCK), jnp.int32),
         interpret=interpret,
+        name="precode_check_blocks",
     )(bits, bits)

@@ -75,23 +75,32 @@ def precode_check_ref(bits: jax.Array) -> jax.Array:
 
 # -- crc32 --------------------------------------------------------------------
 
-def crc32_segments_ref(data: jax.Array, table: jax.Array) -> jax.Array:
-    """Per-segment CRC32 over (R, C, L) int32 bytes."""
-    def step(crc, byte):
-        idx = (crc ^ byte) & 0xFF
-        return jax.lax.shift_right_logical(crc, 8) ^ jnp.take(table, idx, axis=0), None
+def make_crc_table() -> np.ndarray:
+    """Standard reflected CRC-32 (poly 0xEDB88320) byte table as int32."""
+    table = np.empty(256, dtype=np.uint32)
+    for i in range(256):
+        c = np.uint32(i)
+        for _ in range(8):
+            c = (c >> np.uint32(1)) ^ (np.uint32(0xEDB88320) * (c & np.uint32(1)))
+        table[i] = c
+    return table.view(np.int32)
 
-    init = jnp.full(data.shape[:2], jnp.int32(-1))
-    crc, _ = jax.lax.scan(step, init, jnp.moveaxis(data, -1, 0))
-    return ~crc
 
+def crc32_segments_batched_ref(data: jax.Array) -> jax.Array:
+    """Oracle for the batched CRC kernel, table-driven a byte at a time.
 
-def crc32_segments_batched_ref(data: jax.Array, table: jax.Array) -> jax.Array:
-    """Oracle for the batched CRC kernel: (B, R, C, L) -> (B, R, C)."""
-    def step(crc, byte):
-        idx = (crc ^ byte) & 0xFF
-        return jax.lax.shift_right_logical(crc, 8) ^ jnp.take(table, idx, axis=0), None
+    data: (B, W, R, C) int32, word ``w`` of a lane holding its bytes
+    ``[4w, 4w + 4)`` little-endian; returns (B, R, C) lane CRCs.
+    """
+    table = jnp.asarray(make_crc_table())
 
-    init = jnp.full(data.shape[:3], jnp.int32(-1))
-    crc, _ = jax.lax.scan(step, init, jnp.moveaxis(data, -1, 0))
+    def step(crc, word):
+        for k in range(4):
+            byte = jax.lax.shift_right_logical(word, 8 * k) & 0xFF
+            idx = (crc ^ byte) & 0xFF
+            crc = jax.lax.shift_right_logical(crc, 8) ^ jnp.take(table, idx, axis=0)
+        return crc, None
+
+    init = jnp.full((data.shape[0],) + data.shape[2:], jnp.int32(-1))
+    crc, _ = jax.lax.scan(step, init, jnp.moveaxis(data, 1, 0))
     return ~crc

@@ -13,29 +13,20 @@ never touches jax device state — smoke tests must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` where supported; {} on older jax.
-
-    jax.sharding.AxisType only exists from jax 0.5; Auto is already the
-    default there, so omitting the kwarg is behavior-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh with Auto axis types (smoke tests, examples)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh():

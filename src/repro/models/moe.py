@@ -193,9 +193,7 @@ def moe_layer(
             aux = jax.lax.pmean(aux, ep_axis)
         return y.reshape(B_l, S, D).astype(x_l.dtype), aux
 
-    from ..distributed.sharding import shard_map_compat
-
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, w_spec),

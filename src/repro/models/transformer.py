@@ -351,9 +351,7 @@ def sharded_embed_lookup(ctx: ModelContext, table: jax.Array, tokens: jax.Array)
         x = jnp.where(ok[..., None], x, jnp.zeros((), x.dtype))
         return jax.lax.psum(x, "model")
 
-    from ..distributed.sharding import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P("model", None), P(*tok_parts)),

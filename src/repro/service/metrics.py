@@ -193,7 +193,7 @@ def format_summary(snapshot: Mapping[str, Any]) -> str:
         lines.append(
             "engine[%s]: %d batches over %d requests (replace=%d crc=%d),"
             " occupancy %.2f, %d queued (max %d), fallbacks replace=%d crc=%d"
-            % ("device" if engine.get("available") else "cpu-only",
+            % (engine.get("platform", "?"),
                engine.get("batches", 0), engine.get("batched_requests", 0),
                req.get("replace", 0), req.get("crc", 0),
                engine.get("occupancy", 0.0), engine.get("queue_depth", 0),

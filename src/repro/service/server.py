@@ -195,8 +195,8 @@ class ArchiveServer:
         # One batched stage-2 device engine per server, shared by every
         # reader/tenant like the executor and cache pool — cross-reader
         # batching is the whole point (kernels/engine.py). "auto" builds one
-        # when the kernel stack imports (falling back to None — pure CPU —
-        # on hosts without jax); "off"/None/False disables; an object with a
+        # (a failure to build it is the caller's error, not a silent switch
+        # to CPU serving); "off"/None/False disables; an object with a
         # ``replace_markers`` attribute is used as an externally owned
         # engine and is NOT shut down with the server.
         self.device_engine = None
@@ -204,13 +204,10 @@ class ArchiveServer:
         if hasattr(device_engine, "replace_markers"):
             self.device_engine = device_engine
         elif device_engine == "auto":
-            try:
-                from ..kernels.engine import DeviceDecodeEngine
+            from ..kernels.engine import DeviceDecodeEngine
 
-                self.device_engine = DeviceDecodeEngine(**(engine_options or {}))
-                self._owns_engine = True
-            except Exception:  # noqa: BLE001 - no jax/kernels: serve on CPU
-                self.device_engine = None
+            self.device_engine = DeviceDecodeEngine(**(engine_options or {}))
+            self._owns_engine = True
         elif device_engine not in (None, False, "off"):
             raise ValueError(
                 "device_engine must be 'auto', 'off'/None/False, or an engine"

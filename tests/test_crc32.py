@@ -1,8 +1,10 @@
 import zlib
 
+import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.crc32 import RunningCRC, combine_parts, crc32_combine
+from repro.core.crc32 import RunningCRC, combine_lanes, combine_parts, crc32_combine
 
 
 @settings(max_examples=60, deadline=None)
@@ -32,3 +34,22 @@ def test_combine_parts_helper():
 def test_empty_and_identity():
     assert crc32_combine(0, 0, 0) == 0
     assert crc32_combine(0xDEADBEEF, 0, 0) == 0xDEADBEEF
+
+
+@pytest.mark.parametrize("lane_len,lanes,used", [(1, 1, 1), (4, 8, 8), (64, 1024, 1000), (4096, 16, 3)])
+def test_combine_lanes_matches_zlib(lane_len, lanes, used):
+    """The vectorized tree fold equals zlib over the concatenated lanes; a
+    row's unused leading lanes hold 0 and fold in as empty prefixes."""
+    rng = np.random.default_rng(lane_len * 31 + used)
+    blobs = [rng.bytes(lane_len) for _ in range(used)]
+    rows = np.zeros((2, lanes), np.uint32)
+    rows[0, lanes - used :] = [zlib.crc32(b) for b in blobs]
+    rows[1, lanes - 1] = zlib.crc32(blobs[0])
+    out = combine_lanes(rows, lane_len)
+    assert int(out[0]) == zlib.crc32(b"".join(blobs))
+    assert int(out[1]) == zlib.crc32(blobs[0])
+
+
+def test_combine_lanes_rejects_ragged_lane_count():
+    with pytest.raises(ValueError):
+        combine_lanes(np.zeros((1, 3), np.uint32), 4)

@@ -7,7 +7,7 @@ Covers the engine's whole contract surface:
   * CRC parity (device lanes + GF(2) combine + ragged host tail) vs zlib;
   * crossover routing (small/singleton requests take the CPU path and are
     counted as fallbacks) and the derive_crossover math itself;
-  * shutdown-while-queued — futures error, never hang;
+  * shutdown-while-queued and readback failures — futures error, never hang;
   * the threading through codec -> fetcher -> reader -> server, with
     engine stats exported from ``ArchiveServer.metrics()``.
 """
@@ -290,6 +290,57 @@ def test_submit_after_shutdown_raises(rng):
     assert eng.crc32(b"data") == (zlib.crc32(b"data") & 0xFFFFFFFF)
 
 
+def test_import_touches_no_device():
+    """Importing the kernel, engine and service modules initializes no JAX
+    backend: on a TPU host an import-time probe would take the chip."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import repro.kernels, repro.kernels.engine, repro.service\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=120
+    )
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_readback_failure_fails_the_batch(rng, monkeypatch):
+    """A device error surfaces at readback (np.asarray of the output). Every
+    request of that batch must get the error, not wait forever, and the
+    engine must keep serving the next batch."""
+    import repro.kernels.engine as engine_mod
+
+    class Unreadable:
+        def __array__(self, *args, **kwargs):
+            raise RuntimeError("device readback failed")
+
+    real = engine_mod.marker_replace_tiles_multi
+    monkeypatch.setattr(
+        engine_mod, "marker_replace_tiles_multi", lambda *a: Unreadable()
+    )
+    with make_engine(max_delay_s=0.02) as eng:
+        futs = [
+            eng.submit_replace(make_syms(rng, 1000), make_window(rng))
+            for _ in range(3)
+        ]
+        for f in futs:
+            with pytest.raises(RuntimeError, match="readback"):
+                f.result(timeout=10)
+        assert eng.stats()["errors"] >= 1
+        monkeypatch.setattr(engine_mod, "marker_replace_tiles_multi", real)
+        syms, window = make_syms(rng, 1000), make_window(rng)
+        np.testing.assert_array_equal(
+            eng.submit_replace(syms, window).result(timeout=60),
+            cpu_replace(syms, window),
+        )
+
+
 def test_shutdown_idempotent():
     eng = make_engine()
     eng.shutdown()
@@ -385,7 +436,8 @@ def test_server_owns_engine_and_exports_stats(rng, tmp_path):
         got = srv.read_range(h, 0, len(data))
         assert bytes(got) == data
         m = srv.metrics()
-        assert m["engine"]["available"]
+        assert m["engine"]["platform"] == "cpu"
+        assert m["engine"]["interpret"]  # Pallas interprets on CPU only
         # interactive scenario on an interpret host: every stage-2 request
         # fell back to the CPU and the stats prove it
         assert m["engine"]["fallbacks"]["replace"] > 0

@@ -1,5 +1,6 @@
-"""Pallas kernel validation: interpret=True vs pure-jnp oracles, with
-shape/dtype sweeps per the repo convention."""
+"""Device kernel validation against pure-jnp oracles, with shape/dtype
+sweeps per the repo convention. Pallas kernels run with interpret=True
+here; tests/test_tpu_compile.py compiles them for a TPU v5e."""
 
 import zlib
 
@@ -11,23 +12,20 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import crc32_parallel, marker_replace, precode_candidates
 from repro.kernels.crc32 import (
+    BLOCK_WORDS,
     SEG_COLS,
     SEG_ROWS,
-    crc32_segments,
     crc32_segments_batched,
-    make_crc_table,
 )
 from repro.kernels.marker_replace import (
     TILE,
     TILE_COLS,
     TILE_ROWS,
-    marker_replace_tiles,
     marker_replace_tiles_multi,
 )
-from repro.kernels.precode_check import BLOCK, HALO, precode_check_blocks
+from repro.kernels.precode_check import BLOCK, HALO, ROWS, precode_check_blocks
 from repro.kernels.ref import (
     crc32_segments_batched_ref,
-    crc32_segments_ref,
     make_replacement_table,
     marker_replace_multi_ref,
     marker_replace_ref,
@@ -51,7 +49,10 @@ def test_marker_replace_kernel_vs_ref(rng, n_tiles):
     table = jnp.asarray(make_replacement_table(window))
     syms = rng.integers(0, 256 + 32768, (n_tiles, TILE_ROWS, TILE_COLS), dtype=np.int64)
     tiles = jnp.asarray(syms.astype(np.int32))
-    out_kernel = marker_replace_tiles(tiles, table, interpret=True)
+    # one window: a table stack of one, every tile selecting it
+    out_kernel = marker_replace_tiles_multi(
+        tiles, table[None], jnp.zeros(n_tiles, jnp.int32)
+    )
     out_ref = marker_replace_ref(tiles, table)
     np.testing.assert_array_equal(np.asarray(out_kernel), np.asarray(out_ref))
 
@@ -85,7 +86,7 @@ def test_marker_replace_property(n, wlen):
 @pytest.mark.parametrize("n_tiles,n_tables", [(1, 1), (4, 2), (6, 4)])
 def test_marker_replace_multi_kernel_vs_ref(rng, n_tiles, n_tables):
     """Batched multi-window kernel: per-tile table select matches the oracle
-    and the single-table kernel applied table by table."""
+    and the single-table oracle applied table by table."""
     tables_np = np.stack([
         make_replacement_table(rng.integers(0, 256, 32768, dtype=np.uint8))
         for _ in range(n_tables)
@@ -97,16 +98,14 @@ def test_marker_replace_multi_kernel_vs_ref(rng, n_tiles, n_tables):
     )
     tids_np = rng.integers(0, n_tables, n_tiles, dtype=np.int64).astype(np.int32)
     tids = jnp.asarray(tids_np)
-    out = np.asarray(marker_replace_tiles_multi(syms, tables, tids, interpret=True))
+    out = np.asarray(marker_replace_tiles_multi(syms, tables, tids))
     ref = np.asarray(marker_replace_multi_ref(syms, tables, tids))
     np.testing.assert_array_equal(out, ref)
     for t in range(n_tables):
         sel = tids_np == t
         if not sel.any():
             continue
-        single = np.asarray(
-            marker_replace_tiles(syms[sel], tables[t], interpret=True)
-        )
+        single = np.asarray(marker_replace_ref(syms[sel], tables[t]))
         np.testing.assert_array_equal(out[sel], single)
 
 
@@ -115,14 +114,13 @@ def test_marker_replace_multi_kernel_vs_ref(rng, n_tiles, n_tables):
 # ---------------------------------------------------------------------------
 
 def test_precode_kernel_vs_ref(rng):
-    bits = rng.integers(0, 2, (4, BLOCK), dtype=np.int64).astype(np.int32)
-    bits = jnp.asarray(np.concatenate([bits, np.zeros((1, BLOCK), np.int32)]))
-    out_kernel = np.asarray(precode_check_blocks(bits, interpret=True))
-    flat = np.asarray(bits).reshape(-1)
-    for blk in range(4):
-        seg = jnp.asarray(flat[blk * BLOCK : blk * BLOCK + BLOCK + HALO])
-        ref = np.asarray(precode_check_ref(seg))
-        np.testing.assert_array_equal(out_kernel[blk][: BLOCK], np.pad(ref, (0, BLOCK - ref.shape[0])))
+    """Two grid steps of ROWS rows: halos run on into the next row, across
+    the block boundary, and off the end (zeros)."""
+    bits = rng.integers(0, 2, (2 * ROWS, BLOCK), dtype=np.int64).astype(np.int32)
+    out_kernel = np.asarray(precode_check_blocks(jnp.asarray(bits), interpret=True))
+    flat = np.concatenate([bits.reshape(-1), np.zeros(HALO, np.int32)])
+    ref = np.asarray(precode_check_ref(jnp.asarray(flat)))
+    np.testing.assert_array_equal(out_kernel.reshape(-1), ref)
 
 
 @pytest.mark.parametrize("nbytes", [1000, 40_000])
@@ -155,16 +153,24 @@ def test_precode_candidates_find_real_blocks(rng):
 # crc32
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("seg_len", [1, 7, 64])
-def test_crc32_kernel_vs_ref(rng, seg_len):
-    data = rng.integers(0, 256, (SEG_ROWS, SEG_COLS, seg_len), dtype=np.int64).astype(np.int32)
-    table = make_crc_table()
-    out_kernel = np.asarray(crc32_segments(jnp.asarray(data), table, interpret=True))
-    out_ref = np.asarray(crc32_segments_ref(jnp.asarray(data), table))
+def _lane_bytes(words: np.ndarray) -> bytes:
+    """The bytes of one lane: its words, little-endian, in order."""
+    return words.astype("<u4").tobytes()
+
+
+def _random_words(rng, shape) -> np.ndarray:
+    return rng.integers(0, 1 << 32, shape, dtype=np.uint64).astype(np.uint32).view(np.int32)
+
+
+@pytest.mark.parametrize("seg_words", [1, 7, 64])
+def test_crc32_kernel_vs_ref(rng, seg_words):
+    data = _random_words(rng, (1, seg_words, SEG_ROWS, SEG_COLS))
+    out_kernel = np.asarray(crc32_segments_batched(jnp.asarray(data), interpret=True))
+    out_ref = np.asarray(crc32_segments_batched_ref(jnp.asarray(data)))
     np.testing.assert_array_equal(out_kernel, out_ref)
     # spot-check lane (0,0) against zlib
-    seg = bytes(int(b) for b in data[0, 0])
-    assert (int(out_kernel[0, 0]) & 0xFFFFFFFF) == (zlib.crc32(seg) & 0xFFFFFFFF)
+    seg = _lane_bytes(data[0, :, 0, 0])
+    assert (int(out_kernel[0, 0, 0]) & 0xFFFFFFFF) == (zlib.crc32(seg) & 0xFFFFFFFF)
 
 
 @pytest.mark.parametrize("n", [0, 1, 1023, 4096, 100_001])
@@ -173,19 +179,22 @@ def test_crc32_parallel_matches_zlib(rng, n):
     assert crc32_parallel(blob) == (zlib.crc32(blob) & 0xFFFFFFFF)
 
 
-@pytest.mark.parametrize("batch,seg_len", [(1, 1), (2, 7), (4, 16)])
-def test_crc32_batched_kernel_vs_ref(rng, batch, seg_len):
-    data = rng.integers(
-        0, 256, (batch, SEG_ROWS, SEG_COLS, seg_len), dtype=np.int64
-    ).astype(np.int32)
-    table = make_crc_table()
-    out = np.asarray(crc32_segments_batched(jnp.asarray(data), table, interpret=True))
-    ref = np.asarray(crc32_segments_batched_ref(jnp.asarray(data), table))
+@pytest.mark.parametrize(
+    "batch,seg_words", [(1, 1), (2, 7), (4, 16), (2, 2 * BLOCK_WORDS)]
+)
+def test_crc32_batched_kernel_vs_ref(rng, batch, seg_words):
+    """Batch rows are independent; segments longer than one block carry the
+    CRC state across the sequential grid axis."""
+    data = _random_words(rng, (batch, seg_words, SEG_ROWS, SEG_COLS))
+    out = np.asarray(crc32_segments_batched(jnp.asarray(data), interpret=True))
+    ref = np.asarray(crc32_segments_batched_ref(jnp.asarray(data)))
     np.testing.assert_array_equal(out, ref)
-    # each batch row must equal the unbatched kernel on the same lanes
+    # each batch row must equal a batch of one on the same lanes
     for b in range(batch):
-        single = np.asarray(crc32_segments(jnp.asarray(data[b]), table, interpret=True))
-        np.testing.assert_array_equal(out[b], single)
+        single = np.asarray(
+            crc32_segments_batched(jnp.asarray(data[b : b + 1]), interpret=True)
+        )
+        np.testing.assert_array_equal(out[b], single[0])
     # spot-check one lane against zlib
-    seg = bytes(int(x) for x in data[-1, 0, 0])
+    seg = _lane_bytes(data[-1, :, 0, 0])
     assert (int(out[-1, 0, 0]) & 0xFFFFFFFF) == (zlib.crc32(seg) & 0xFFFFFFFF)

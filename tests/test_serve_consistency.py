@@ -77,14 +77,6 @@ def test_decode_matches_forward(arch, ctx):
     # carries larger-but-bounded rounding noise; the fp32 equivalence is
     # pinned exactly by test_mla_absorbed_exact_fp32 below.
     tol = 1.5e-1 if cfg.use_mla else 2e-2
-    if cfg.use_mla and tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5):
-        # jax 0.4.x (container ships 0.4.37): CPU dot_general tiles bf16
-        # contractions shape-dependently, so the train-shaped forward and the
-        # decode-shaped MLA/MoE calls round differently — 2/1024 logits land
-        # up to ~0.20 apart (forcing fp32 accumulation in the MoE combine
-        # does not close it; the reassociated MLA decode dominates). jax 0.5+
-        # stays within the 1.5e-1 bound.
-        tol = 2.5e-1
     for t in range(S_pre, S_total):
         logits_d, caches = model.decode_step(
             params, tokens[:, t : t + 1], caches, jnp.int32(t + prefix_len), ctx
