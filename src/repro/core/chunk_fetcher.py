@@ -34,6 +34,7 @@ from __future__ import annotations
 import threading
 import zlib as _zlib
 from concurrent.futures import CancelledError, Future, ThreadPoolExecutor
+from contextvars import ContextVar
 from dataclasses import dataclass, field
 import time as _time
 from typing import Callable, Dict, List, Optional, Tuple, Union
@@ -44,7 +45,7 @@ from ..obs import trace as _obs_trace
 from .cache import LRUCache
 from .codec import Codec, resolve_codec
 from .deflate import DecodeResult
-from .errors import BlockNotFoundError, DeflateError, EndOfStream, RapidgzipError
+from .errors import BlockNotFoundError, DeflateError, EndOfStream, FormatError, RapidgzipError
 from .filereader import FileReader
 from .index import (
     FLAG_HAS_INTERIOR_MEMBER_END,
@@ -60,6 +61,11 @@ DEFAULT_CHUNK_SIZE = 4 << 20  # paper §1.4: 4 MiB default compressed chunk size
 #: against runaway false positives without rejecting any legal chunk.
 MAX_COMPRESSION_RATIO = 1100
 
+#: The live ``fetcher.task`` span of the task running on this thread, set
+#: only while tracing is on: a task body adds what only it can see (the
+#: block finder's share of a nominal task) to the span `_run_task` opened.
+_task_span: ContextVar = ContextVar("repro_fetcher_task_span", default=None)
+
 
 @dataclass
 class FetcherStats:
@@ -68,7 +74,6 @@ class FetcherStats:
     indexed_tasks: int = 0
     candidates_tried: int = 0
     false_positive_starts: int = 0  # candidates that failed trial decompression
-    false_positive_chunks: int = 0  # full chunk results never matched by a request
     redispatches: int = 0  # exact task after prefetch mismatch
     chunks_with_markers: int = 0
     zlib_delegations: int = 0
@@ -358,8 +363,19 @@ class ChunkFetcher:
             attach_ctx = ctx if _obs_trace.current_context() is None else None
             with _obs_trace.attach(attach_ctx), _obs_trace.span(
                 "fetcher.task", {"kind": key[0], "key": str(key[1])}
-            ):
-                return fn(*args)
+            ) as sp:
+                # The thread's CPU time against the span's wall time tells
+                # decoding from waiting for the interpreter lock.
+                token = _task_span.set(sp)
+                cpu0 = _time.thread_time()
+                value = None
+                try:
+                    value = fn(*args)
+                    return value
+                finally:
+                    sp.set_attr("cpu_s", _time.thread_time() - cpu0)
+                    sp.set_attr("bytes", _decoded_bytes(value))
+                    _task_span.reset(token)
         finally:
             with self._lock:
                 self._in_flight.pop(key, None)
@@ -413,6 +429,19 @@ class ChunkFetcher:
         header) enabling single-stage decode; None means two-stage marker
         mode.
         """
+        if not _obs_trace.tracing_enabled():
+            return self._chunk_at(bit_offset, window)[0]
+        # The frontier's wait for one chunk, by where the chunk came from.
+        with _obs_trace.span("fetcher.chunk_wait", {"bit": bit_offset}) as sp:
+            res, source = self._chunk_at(bit_offset, window)
+            sp.set_attr("source", source)
+            sp.set_attr("bytes", res.size)
+            return res
+
+    def _chunk_at(self, bit_offset: int, window: Optional[bytes]) -> Tuple[DecodeResult, str]:
+        """`get_chunk_at`'s result and its source: ``cache`` (a prefetched
+        result was ready), ``nominal`` (joined an in-flight speculative
+        task) or ``exact`` (an exact task ran, redispatches included)."""
         k = self.nominal_index_of(bit_offset)
         self.trigger_prefetch(k)
 
@@ -421,7 +450,7 @@ class ChunkFetcher:
         if res is not None:
             # Marker-mode results are fine even when the window is known:
             # finalize_async resolves them with the supplied window.
-            return res
+            return res, "cache"
 
         # A nominal prefetch covering this offset may be in flight — its
         # result is only usable if its speculative start matched exactly.
@@ -438,7 +467,7 @@ class ChunkFetcher:
                 # fall through to a fresh exact task, like any other miss.
                 nom_res = None
             if nom_res is not None and nom_res.start_bit == bit_offset:
-                return nom_res
+                return nom_res, "nominal"
             with self._lock:
                 self.stats.redispatches += 1
 
@@ -450,7 +479,7 @@ class ChunkFetcher:
                                     cost=cost)
         if res is None:
             raise RapidgzipError("exact chunk decode failed at bit %d" % bit_offset)
-        return res
+        return res, "exact"
 
     # -- tasks ----------------------------------------------------------
 
@@ -483,14 +512,21 @@ class ChunkFetcher:
 
         failed: set = set()
         result: Optional[DecodeResult] = None
+        sp = _task_span.get() if _obs_trace.tracing_enabled() else None
+        find_s = [0.0]
+        trials = 0
         for (buf, base), at_eof in self._margins(start_bit // 8, stop_bit // 8):
             base_bits = base * 8
             local_start = start_bit - base_bits
             local_stop = stop_bit - base_bits
             need_more_data = False
-            for cand in self.codec.find_chunk_starts(buf, local_start, local_stop):
+            args = (buf, local_start, local_stop)
+            cands = (self.codec.find_chunk_starts(*args) if sp is None
+                     else _clocked(find_s, self.codec.find_chunk_starts, *args))
+            for cand in cands:
                 if cand + base_bits in failed:
                     continue
+                trials += 1
                 with self._lock:
                     self.stats.candidates_tried += 1
                 try:
@@ -509,7 +545,10 @@ class ChunkFetcher:
                         self.stats.false_positive_starts += 1
                     failed.add(cand + base_bits)
                     continue
-                except DeflateError:
+                except FormatError:
+                    # Bad deflate data, or a trial that ran past a final
+                    # block into bytes that are no gzip header: either way
+                    # the candidate was no chunk start.
                     with self._lock:
                         self.stats.false_positive_starts += 1
                     failed.add(cand + base_bits)
@@ -518,6 +557,9 @@ class ChunkFetcher:
                 break
             if result is not None or not need_more_data:
                 break
+        if sp is not None:
+            sp.set_attr("find_s", find_s[0])
+            sp.set_attr("trials", trials)
 
         with self._lock:
             self._nominal_done[k] = result.start_bit if result is not None else None
@@ -761,6 +803,31 @@ class ChunkFetcher:
 #: been codec-parameterized (``codec=`` kwarg) but the default construction
 #: is unchanged, so existing callers keep working.
 GzipChunkFetcher = ChunkFetcher
+
+
+def _decoded_bytes(value) -> int:
+    """Decoded size of a task's result: a first-pass `DecodeResult`, an
+    indexed chunk's bytes, or nothing found (0)."""
+    if isinstance(value, DecodeResult):
+        return value.size
+    return int(getattr(value, "nbytes", 0))
+
+
+def _clocked(spent: List[float], make, *args):
+    """Iterate ``make(*args)``, adding the wall time spent inside it (the
+    call and every step, not the consumer's work between steps) to
+    ``spent[0]``."""
+    t0 = _time.perf_counter()
+    it = iter(make(*args))
+    while True:
+        try:
+            item = next(it)
+        except StopIteration:
+            spent[0] += _time.perf_counter() - t0
+            return
+        spent[0] += _time.perf_counter() - t0
+        yield item
+        t0 = _time.perf_counter()
 
 
 def _offset_result(res: DecodeResult, base_bits: int) -> DecodeResult:

@@ -66,6 +66,16 @@ from .markers import full_window
 _NESTED_PREAD_RECORD_S = 5e-4
 
 
+def _stage2_wait(part: str, fn, *args):
+    """``fn(*args)``, a blocking stage-2 call on the frontier: under tracing
+    a ``reader.stage2_wait`` span, whose ``part`` is ``replace`` (marker
+    resolution) or ``crc`` (CRC32)."""
+    if not _obs_trace.tracing_enabled():
+        return fn(*args)
+    with _obs_trace.span("reader.stage2_wait", {"part": part}):
+        return fn(*args)
+
+
 class ParallelGzipReader(io.RawIOBase):
     """File-like object exposing the decompressed stream of a gzip file."""
 
@@ -267,15 +277,16 @@ class ParallelGzipReader(io.RawIOBase):
     def _collect(self, fc: FinalizedChunk) -> None:
         """Sequential bookkeeping for one finalized chunk: CRC verification,
         seek points (with interior splits), and byte handoff to the cache."""
-        data = fc.bytes()
         res = fc.result
+        # Only a marker-mode chunk has a replacement in flight to wait on.
+        data = _stage2_wait("replace", fc.bytes) if res.marker_mode else fc.bytes()
 
         # -- CRC32 / ISIZE verification at member ends ---------------------
         if self._verify and self._codec.verifies_members:
             prev = 0
             for me in res.member_ends:
                 seg = data[prev : me.out_offset]
-                crc = self._fetcher.crc32(seg)
+                crc = _stage2_wait("crc", self._fetcher.crc32, seg)
                 self._member_crc = crc32_combine(self._member_crc, crc, int(seg.shape[0]))
                 self._member_len += int(seg.shape[0])
                 if self._member_crc != me.crc32:
@@ -290,7 +301,7 @@ class ParallelGzipReader(io.RawIOBase):
                 prev = me.out_offset
             tail = data[prev:]
             if tail.shape[0]:
-                crc = self._fetcher.crc32(tail)
+                crc = _stage2_wait("crc", self._fetcher.crc32, tail)
                 self._member_crc = crc32_combine(self._member_crc, crc, int(tail.shape[0]))
                 self._member_len += int(tail.shape[0])
 

@@ -7,8 +7,10 @@ Design constraints (the pread hot path runs through here):
     Nothing allocates, nothing takes a lock, no clock is read.
   * **Ring buffer, monotonic clocks.** Finished spans land in a bounded
     deque (oldest dropped); durations come from ``perf_counter`` and
-    timestamps are wall-anchored once at import so a trace file lines up
-    with log timestamps without ever going backwards.
+    timestamps are wall-anchored (at import, and again by every
+    `enable_tracing()`) so a trace file lines up with log timestamps and
+    with a profiler trace started right after, without ever going
+    backwards.
   * **Propagation.** The current span context lives in a `ContextVar`, so
     it follows asyncio tasks for free. Thread hops (executor submit →
     worker, async bridge, engine dispatcher) carry it explicitly:
@@ -47,6 +49,8 @@ SpanContext = Tuple[str, str]  # (trace_id, span_id)
 #: Wall-clock anchor: span timestamps are ``_WALL0 + (perf_counter() -
 #: _MONO0)`` — monotone within the process, comparable across processes to
 #: within clock skew (good enough to line a trace up with server logs).
+#: `enable_tracing()` re-takes it, so the two clocks cannot drift apart over
+#: a long set-up before the traced window that is joined to a device trace.
 _WALL0 = time.time()
 _MONO0 = time.perf_counter()
 
@@ -118,11 +122,12 @@ def enable_tracing(capacity: Optional[int] = None) -> None:
     """Turn the recorder on. ``capacity`` sizes the ring buffer; None means
     the default (8192), not "keep the current size" — so enable/disable
     cycles are deterministic regardless of what a previous caller chose."""
-    global _enabled, _spans
+    global _enabled, _spans, _WALL0, _MONO0
     want = max(1, capacity if capacity is not None else _DEFAULT_CAPACITY)
     with _lock:
         if want != _spans.maxlen:
             _spans = deque(_spans, maxlen=want)
+        _WALL0, _MONO0 = time.time(), time.perf_counter()
         _enabled = True
 
 
