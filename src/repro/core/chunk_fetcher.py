@@ -20,7 +20,9 @@ Orchestrates parallel chunk decompression:
     (paper §2.2's Amdahl mitigation).
 
 Work distribution is dynamic: whichever worker is free takes the next
-dispatched chunk — the paper's straggler mitigation (§4.2, §6).
+dispatched chunk — the paper's straggler mitigation (§4.2, §6). With an
+injected ``stage1_pool`` the decode of a nominal or exact task runs in a
+worker process (``stage1_worker``) while the task's thread waits on it.
 
 ``get_indexed`` is safe to call from many threads concurrently: caches,
 in-flight dedup, and the index carry their own locks, and stateful prefetch
@@ -45,7 +47,7 @@ from ..obs import trace as _obs_trace
 from .cache import LRUCache
 from .codec import Codec, resolve_codec
 from .deflate import DecodeResult
-from .errors import BlockNotFoundError, DeflateError, EndOfStream, FormatError, RapidgzipError
+from .errors import BlockNotFoundError, DeflateError, EndOfStream, RapidgzipError
 from .filereader import FileReader
 from .index import (
     FLAG_HAS_INTERIOR_MEMBER_END,
@@ -55,6 +57,7 @@ from .index import (
     SeekPoint,
 )
 from .prefetch import AdaptivePrefetchStrategy, PrefetchStrategy
+from . import stage1_worker as _stage1
 
 DEFAULT_CHUNK_SIZE = 4 << 20  # paper §1.4: 4 MiB default compressed chunk size
 #: deflate's maximum compression ratio is ~1032 (paper §1.4); the cap guards
@@ -63,7 +66,8 @@ MAX_COMPRESSION_RATIO = 1100
 
 #: The live ``fetcher.task`` span of the task running on this thread, set
 #: only while tracing is on: a task body adds what only it can see (the
-#: block finder's share of a nominal task) to the span `_run_task` opened.
+#: block finder's share of a nominal task, a pool worker's CPU time) to the
+#: span `_run_task` opened.
 _task_span: ContextVar = ContextVar("repro_fetcher_task_span", default=None)
 
 
@@ -78,6 +82,7 @@ class FetcherStats:
     chunks_with_markers: int = 0
     zlib_delegations: int = 0
     bytes_decompressed: int = 0
+    stage1_offloaded: int = 0  # nominal and exact tasks decoded in the stage-1 pool
 
     def as_dict(self) -> dict:
         return {k: int(getattr(self, k)) for k in self.__dataclass_fields__}
@@ -145,6 +150,7 @@ class ChunkFetcher:
         access_cache: Optional[LRUCache] = None,
         prefetch_cache: Optional[LRUCache] = None,
         resolver=None,
+        stage1_pool=None,
     ):
         if chunk_size < 1 << 10:
             raise ValueError("chunk_size must be >= 1 KiB")
@@ -186,6 +192,11 @@ class ChunkFetcher:
             prefetch_cache if prefetch_cache is not None else LRUCache(2 * self.parallelization)
         )
         self.strategy = prefetch_strategy or AdaptivePrefetchStrategy(self.parallelization)
+        # Stage-1 process pool (stage1_worker.start_pool), externally owned
+        # like the executor: first-pass decodes of a codec a worker can
+        # rebuild run there, so they stop sharing one interpreter lock.
+        # None decodes on the executor thread.
+        self.stage1_pool = stage1_pool if _stage1.offloadable(self.codec) else None
 
         self._lock = threading.Lock()
         # Flipped at shutdown: _blocking_result must stop resubmitting after
@@ -364,16 +375,20 @@ class ChunkFetcher:
             with _obs_trace.attach(attach_ctx), _obs_trace.span(
                 "fetcher.task", {"kind": key[0], "key": str(key[1])}
             ) as sp:
-                # The thread's CPU time against the span's wall time tells
-                # decoding from waiting for the interpreter lock.
+                # The CPU time spent on the task (this thread's, plus a pool
+                # worker's when `_decode` sent it there) against the span's
+                # wall time tells decoding from waiting for the interpreter
+                # lock.
                 token = _task_span.set(sp)
+                sp.set_attr("offloaded", False)
+                sp.set_attr("cpu_s", 0.0)
                 cpu0 = _time.thread_time()
                 value = None
                 try:
                     value = fn(*args)
                     return value
                 finally:
-                    sp.set_attr("cpu_s", _time.thread_time() - cpu0)
+                    sp.attrs["cpu_s"] += _time.thread_time() - cpu0
                     sp.set_attr("bytes", _decoded_bytes(value))
                     _task_span.reset(token)
         finally:
@@ -484,14 +499,39 @@ class ChunkFetcher:
     # -- tasks ----------------------------------------------------------
 
     def _margins(self, start_byte: int, stop_byte: int):
-        """Yield growing (buffer, base) windows until EOF is covered."""
+        """Yield growing (buffer, base) windows until EOF is covered.
+
+        With a stage-1 pool each window is exactly ``[start_byte, end)``:
+        a worker is sent the bytes it may read, never the whole archive
+        an in-memory source would lend zero-copy.
+        """
         margin = max(2 * self.chunk_size, 1 << 20)
         while True:
             end = min(stop_byte + margin, self.file_size)
-            yield self._buffer(start_byte, end), end >= self.file_size
+            if self.stage1_pool is None:
+                window = self._buffer(start_byte, end)
+            else:
+                window = (self.reader.pread(start_byte, end - start_byte), start_byte)
+            yield window, end >= self.file_size
             if end >= self.file_size:
                 return
             margin *= 4
+
+    def _decode(self, fn, buf, base: int, *args, **kwargs):
+        """``fn(codec, buf, base, *args, **kwargs)`` of `stage1_worker`:
+        in the stage-1 pool when there is one, the executor thread blocking
+        on the future (which releases the interpreter lock), else here."""
+        if self.stage1_pool is None:
+            return fn(self.codec, buf, base, *args, **kwargs)
+        value, cpu_s = self.stage1_pool.submit(
+            _stage1.in_worker, fn, self.codec.tag, self.framing,
+            buf, base, *args, **kwargs,
+        ).result()
+        sp = _task_span.get() if _obs_trace.tracing_enabled() else None
+        if sp is not None:
+            sp.set_attr("offloaded", True)
+            sp.attrs["cpu_s"] += cpu_s
+        return value
 
     def _task_nominal(self, k: int) -> Optional[DecodeResult]:
         if not self.codec.supports_speculation:
@@ -503,6 +543,8 @@ class ChunkFetcher:
             return None
         with self._lock:
             self.stats.nominal_tasks += 1
+            if self.stage1_pool is not None:
+                self.stats.stage1_offloaded += 1
         start_bit = k * self.chunk_size * 8
         stop_bit = self._nominal_stop_bit(k)
         if start_bit >= self.total_bits:
@@ -513,52 +555,25 @@ class ChunkFetcher:
         failed: set = set()
         result: Optional[DecodeResult] = None
         sp = _task_span.get() if _obs_trace.tracing_enabled() else None
-        find_s = [0.0]
+        find_s = 0.0
         trials = 0
         for (buf, base), at_eof in self._margins(start_bit // 8, stop_bit // 8):
-            base_bits = base * 8
-            local_start = start_bit - base_bits
-            local_stop = stop_bit - base_bits
-            need_more_data = False
-            args = (buf, local_start, local_stop)
-            cands = (self.codec.find_chunk_starts(*args) if sp is None
-                     else _clocked(find_s, self.codec.find_chunk_starts, *args))
-            for cand in cands:
-                if cand + base_bits in failed:
-                    continue
-                trials += 1
-                with self._lock:
-                    self.stats.candidates_tried += 1
-                try:
-                    res = self.codec.decode_chunk(
-                        buf,
-                        cand,
-                        local_stop,
-                        window=None,
-                        max_out=self.max_ratio * self.chunk_size,
-                    )
-                except EndOfStream:
-                    if not at_eof:
-                        need_more_data = True
-                        break
-                    with self._lock:
-                        self.stats.false_positive_starts += 1
-                    failed.add(cand + base_bits)
-                    continue
-                except FormatError:
-                    # Bad deflate data, or a trial that ran past a final
-                    # block into bytes that are no gzip header: either way
-                    # the candidate was no chunk start.
-                    with self._lock:
-                        self.stats.false_positive_starts += 1
-                    failed.add(cand + base_bits)
-                    continue
-                result = _offset_result(res, base_bits)
-                break
-            if result is not None or not need_more_data:
+            trial = self._decode(
+                _stage1.trial_decode, buf, base, start_bit, stop_bit,
+                max_out=self.max_ratio * self.chunk_size, at_eof=at_eof,
+                failed=failed, clock=sp is not None,
+            )
+            failed.update(trial.failed)
+            trials += trial.trials
+            find_s += trial.find_s
+            with self._lock:
+                self.stats.candidates_tried += trial.trials
+                self.stats.false_positive_starts += len(trial.failed)
+            result = trial.result
+            if result is not None or not trial.need_more_data:
                 break
         if sp is not None:
-            sp.set_attr("find_s", find_s[0])
+            sp.set_attr("find_s", find_s)
             sp.set_attr("trials", trials)
 
         with self._lock:
@@ -576,25 +591,22 @@ class ChunkFetcher:
     def _task_exact(self, bit_offset: int, window: Optional[bytes]) -> DecodeResult:
         with self._lock:
             self.stats.exact_tasks += 1
+            if self.stage1_pool is not None:
+                self.stats.stage1_offloaded += 1
         k = self.nominal_index_of(bit_offset)
         stop_bit = max(self._nominal_stop_bit(k), bit_offset + 1)
         last_err: Optional[Exception] = None
         for (buf, base), at_eof in self._margins(bit_offset // 8, stop_bit // 8):
-            base_bits = base * 8
             try:
-                res = self.codec.decode_chunk(
-                    buf,
-                    bit_offset - base_bits,
-                    stop_bit - base_bits,
-                    window=window,
-                    max_out=self.max_ratio * self.chunk_size,
+                res = self._decode(
+                    _stage1.exact_decode, buf, base, bit_offset, stop_bit,
+                    window=window, max_out=self.max_ratio * self.chunk_size,
                 )
             except EndOfStream as exc:
                 if not at_eof:
                     last_err = exc
                     continue
                 raise
-            res = _offset_result(res, base_bits)
             self._insert_hinted(
                 self.prefetch_cache, ("fp", bit_offset), res,
                 recompute_cost=self._result_cost(res),
@@ -811,36 +823,3 @@ def _decoded_bytes(value) -> int:
     if isinstance(value, DecodeResult):
         return value.size
     return int(getattr(value, "nbytes", 0))
-
-
-def _clocked(spent: List[float], make, *args):
-    """Iterate ``make(*args)``, adding the wall time spent inside it (the
-    call and every step, not the consumer's work between steps) to
-    ``spent[0]``."""
-    t0 = _time.perf_counter()
-    it = iter(make(*args))
-    while True:
-        try:
-            item = next(it)
-        except StopIteration:
-            spent[0] += _time.perf_counter() - t0
-            return
-        spent[0] += _time.perf_counter() - t0
-        yield item
-        t0 = _time.perf_counter()
-
-
-def _offset_result(res: DecodeResult, base_bits: int) -> DecodeResult:
-    """Translate a buffer-local DecodeResult to global bit offsets."""
-    if base_bits == 0:
-        return res
-    res.start_bit += base_bits
-    res.end_bit += base_bits
-    for b in res.blocks:
-        b.bit_offset += base_bits
-    for me in res.member_ends:
-        me.footer_end_bit += base_bits
-    for ms in res.member_starts:
-        ms.header_start_bit += base_bits
-        ms.deflate_start_bit += base_bits
-    return res

@@ -96,6 +96,7 @@ class ParallelGzipReader(io.RawIOBase):
         prefetch_cache=None,
         prefetch_strategy=None,
         resolver=None,
+        stage1_pool=None,
     ):
         super().__init__()
         self._reader = open_file_reader(source)
@@ -140,6 +141,7 @@ class ParallelGzipReader(io.RawIOBase):
                 prefetch_cache=prefetch_cache,
                 prefetch_strategy=prefetch_strategy,
                 resolver=resolver,
+                stage1_pool=stage1_pool,
             )
             self._index = self._fetcher.index
 

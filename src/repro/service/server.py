@@ -10,7 +10,11 @@ reader over one file. This server multiplexes a registry of
   * **CPU** — every reader's fetcher submits into one `FairExecutor`
     (byte-weighted deficit round-robin + per-tenant priority lanes), so a
     hot tenant's prefetch stream cannot starve another tenant's first read,
-    measured in bytes of decompression work rather than task counts;
+    measured in bytes of decompression work rather than task counts; the
+    first-pass decodes of gzip and raw deflate run in one shared pool of
+    worker processes (`core/stage1_worker.py`), so they do not queue on
+    the interpreter lock. Its workers are spawned, so a script that builds
+    a server runs that code under an ``if __name__ == "__main__"`` guard;
   * **index reuse** — opens consult an `IndexStore`; a warm hit skips the
     speculative first pass entirely (zero nominal tasks), closes persist
     finalized indexes back.
@@ -58,6 +62,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ..core import stage1_worker
 from ..core.reader import ParallelGzipReader
 from ..core.remote import RemoteFileReader, is_remote_url
 from ..obs import hist as _obs_hist
@@ -232,6 +237,13 @@ class ArchiveServer:
             raise ValueError(
                 "transcode must be 'auto', 'off'/None/False, or a manager"
             )
+        # Stage-1 decodes of speculative codecs run in worker processes, a
+        # core each, instead of queueing on the interpreter lock in the
+        # executor's threads: sized like the executor, capped at the usable
+        # CPUs, absent below two. Started without waiting, so the workers'
+        # start-up overlaps the engine's warm-up; owned by the server and
+        # stopped in shutdown().
+        self.stage1_pool = stage1_worker.start_pool(max_workers)
         self.chunk_size = chunk_size
         self.reader_parallelization = reader_parallelization
         self.access_cache_entries = access_cache_entries
@@ -369,6 +381,7 @@ class ArchiveServer:
                     access_cache=access_cache,
                     prefetch_cache=prefetch_cache,
                     resolver=self.device_engine,
+                    stage1_pool=self.stage1_pool,
                 )
                 entry.codec = entry.reader.codec.tag
             except BaseException:
@@ -712,6 +725,10 @@ class ArchiveServer:
             self.transcoder.close()
         self.close_all()
         self.executor.shutdown(wait=False, cancel_futures=True)
+        if self.stage1_pool is not None:
+            # Running decodes finish, queued ones are dropped, and every
+            # worker is joined: no child process outlives the server.
+            self.stage1_pool.shutdown(wait=True, cancel_futures=True)
         # After the executor: no pool worker can submit to the engine once
         # the pool is down, so queued engine futures error instead of hang.
         if self._owns_engine and self.device_engine is not None:
