@@ -12,8 +12,9 @@ One process owns the chip and starts no other process. Phases, in order:
 3. served reads at deployment size through ``ArchiveServer`` with the
    device engine forced on — a gzip -6 archive of mixed text (speculative
    two-stage decode, so marker resolution and CRC32 on the device) and a
-   BGZF FASTQ-like archive larger than the cache pool (zlib delegate, CRC32
-   on the device); a cold full read, seeded random preads, a warm reopen
+   BGZF FASTQ-like archive larger than the cache pool (zlib inflate, every
+   member's CRC32 on the device); a cold full read, after which the engine
+   must have CRC'd every byte it served, seeded random preads, a warm reopen
    through the same ``IndexStore``, and preads through a loopback gateway,
    every byte compared with the generated source;
 4. engine check — the engine ran on the chip, uninterpreted, with no CPU
@@ -109,7 +110,8 @@ def kernel_parity(rng, *, tiles=32, tables=8, crc_batch=16, crc_words=1024,
 
     from repro.kernels import ref
     from repro.kernels.crc32 import (
-        N_SEGMENTS, SEG_COLS, SEG_ROWS, crc32_segments_batched, finish_crcs, pack_lanes,
+        N_SEGMENTS, SEG_COLS, SEG_ROWS, crc32_lanes, crc32_segments_batched, finish_parts,
+        pack_parts, parts_words,
     )
     from repro.kernels.marker_replace import TILE_COLS, TILE_ROWS, marker_replace_tiles_multi
     from repro.kernels.ops import interpret_on
@@ -136,8 +138,8 @@ def kernel_parity(rng, *, tiles=32, tables=8, crc_batch=16, crc_words=1024,
     log("  marker_replace_tiles_multi %s x %d tables: bit-identical"
         % ((tiles, TILE_ROWS, TILE_COLS), tables))
 
-    # CRC32: whole lanes against the oracle and zlib, then ragged requests
-    # folded back to one CRC each.
+    # CRC32: whole lanes against the oracle and zlib, then ragged parts laid
+    # into one row as the engine lays a batch, folded back to one CRC each.
     words = rng.integers(0, 1 << 32, (crc_batch, crc_words, SEG_ROWS, SEG_COLS),
                          dtype=np.uint64).astype(np.uint32).view(np.int32)
     lanes = np.asarray(crc32_segments_batched(
@@ -147,20 +149,19 @@ def kernel_parity(rng, *, tiles=32, tables=8, crc_batch=16, crc_words=1024,
     per_lane = words.astype("<u4").transpose(0, 2, 3, 1).reshape(crc_batch * N_SEGMENTS, -1)
     want = np.array([zlib.crc32(row.tobytes()) for row in per_lane], np.uint32)
     check(np.array_equal(lanes.reshape(-1).view(np.uint32), want), "crc32 lanes != zlib")
-    seg_bytes = crc_words * 4 * N_SEGMENTS
-    sizes = [min(n, seg_bytes) for n in (1, 3, 4096, 65280)] + [
-        int(n) for n in rng.integers(1, seg_bytes + 1, crc_batch - 5)
-    ] + [seg_bytes]
+    sizes = [0, 1, 3, 4096, 65280] + [
+        int(n) for n in rng.integers(1, 200_000, crc_batch - 5)
+    ]
     datas = [rng.bytes(n) for n in sizes]
-    stage = np.zeros(words.shape, np.int32)
-    for row, data in zip(stage, datas):
-        pack_lanes(row, data)
-    lanes = np.asarray(crc32_segments_batched(
-        jax.device_put(stage, device), interpret=interpret))
-    got = finish_crcs(lanes, datas, crc_words)
+    row_words = parts_words(sizes)
+    stage = np.zeros((1, N_SEGMENTS, row_words), np.int32)
+    pack_parts(stage[0], datas)
+    lanes = np.asarray(crc32_lanes(jax.device_put(stage, device), interpret=interpret))
+    got = finish_parts(lanes[0], sizes, row_words)
     check(got == [zlib.crc32(d) for d in datas], "folded crc32 != zlib.crc32")
-    log("  crc32_segments_batched %s: lanes and %d ragged requests bit-identical"
-        % (words.shape, len(datas)))
+    log("  crc32_segments_batched %s: lanes bit-identical; %d ragged parts in one %s"
+        " lane-major row too"
+        % (words.shape, len(datas), stage.shape))
 
     # Block-finder precheck over one chunk of bit offsets.
     bits = np.unpackbits(np.frombuffer(rng.bytes(precode_bytes), np.uint8),
@@ -212,11 +213,14 @@ def served_reads(srv, archives, rng, *, n_preads: int, n_gateway_preads: int) ->
     from repro.service.gateway import GatewayClient, GatewayServer
 
     for label, path, source in archives:
-        before = srv.device_engine.stats()["requests"]
+        before = srv.device_engine.stats()
         t = time.perf_counter()
         h = srv.open(path)
         check(srv.read_range(h, 0, len(source)) == source, "%s cold read differs" % label)
         cold = time.perf_counter() - t
+        crc_bytes = srv.device_engine.stats()["crc_bytes"] - before["crc_bytes"]
+        check(crc_bytes >= len(source),
+              "%s cold read: the engine CRC'd %d of %d bytes" % (label, crc_bytes, len(source)))
         t = time.perf_counter()
         nbytes = _preads(lambda o, n: srv.read_range(h, o, n), source, rng, n_preads, label)
         hot = time.perf_counter() - t
@@ -229,10 +233,11 @@ def served_reads(srv, archives, rng, *, n_preads: int, n_gateway_preads: int) ->
         warm = time.perf_counter() - t
         srv.close(h)
         after = srv.device_engine.stats()["requests"]
-        log("  %s: cold full read %r s; %d preads (%d bytes) %r s;"
+        log("  %s: cold full read %r s, %d bytes CRC'd on the engine; %d preads (%d bytes) %r s;"
             " warm reopen + %d preads %r s; engine requests replace=%d crc=%d"
-            % (label, cold, n_preads, nbytes, hot, n_preads, warm,
-               after["replace"] - before["replace"], after["crc"] - before["crc"]))
+            % (label, cold, crc_bytes, n_preads, nbytes, hot, n_preads, warm,
+               after["replace"] - before["requests"]["replace"],
+               after["crc"] - before["requests"]["crc"]))
 
     with GatewayServer(srv) as gw:
         for label, path, source in archives:

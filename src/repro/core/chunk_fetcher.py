@@ -14,7 +14,11 @@ Orchestrates parallel chunk decompression:
   * **Indexed tasks** — once seek points exist, chunks decompress from their
     recorded (bit offset, window) — delegated to zlib where possible (paper
     §1.3: >2x faster than two-stage), falling back to the custom decoder for
-    chunks containing gzip member boundaries.
+    chunks containing gzip member boundaries. Where every chunk is a whole
+    member with its own trailer (BGZF), a task inflates a run of them
+    (``task_points``) and checks each one's ISIZE and CRC32, the CRCs in one
+    request to the stage-2 resolver, before any byte is cached; a cold read
+    whose run is not on the way gets its member alone first.
   * **Finalization** — window propagation is the only sequential step (last
     32 KiB per chunk); full marker replacement and CRC parts run on the pool
     (paper §2.2's Amdahl mitigation).
@@ -47,7 +51,14 @@ from ..obs import trace as _obs_trace
 from .cache import LRUCache
 from .codec import Codec, resolve_codec
 from .deflate import DecodeResult
-from .errors import BlockNotFoundError, DeflateError, EndOfStream, RapidgzipError
+from .errors import (
+    BlockNotFoundError,
+    DeflateError,
+    EndOfStream,
+    FormatError,
+    GzipFooterError,
+    RapidgzipError,
+)
 from .filereader import FileReader
 from .index import (
     FLAG_HAS_INTERIOR_MEMBER_END,
@@ -81,8 +92,11 @@ class FetcherStats:
     redispatches: int = 0  # exact task after prefetch mismatch
     chunks_with_markers: int = 0
     zlib_delegations: int = 0
-    bytes_decompressed: int = 0
+    bytes_decompressed: int = 0  # first pass finalized; trailer members inflated
     stage1_offloaded: int = 0  # nominal and exact tasks decoded in the stage-1 pool
+    members_verified: int = 0  # trailer members whose CRC32 and ISIZE were checked
+    member_crc_mismatches: int = 0  # trailer members whose CRC32 or ISIZE did not match
+    member_crc_device_bytes: int = 0  # bytes of those members CRC'd on the device
 
     def as_dict(self) -> dict:
         return {k: int(getattr(self, k)) for k in self.__dataclass_fields__}
@@ -151,6 +165,7 @@ class ChunkFetcher:
         prefetch_cache: Optional[LRUCache] = None,
         resolver=None,
         stage1_pool=None,
+        verify: bool = True,
     ):
         if chunk_size < 1 << 10:
             raise ValueError("chunk_size must be >= 1 KiB")
@@ -169,6 +184,9 @@ class ChunkFetcher:
                 % (self.index.codec_tag, self.codec.tag)
             )
         self.max_ratio = max_ratio
+        self.verify = verify
+        self._task_points: Optional[int] = None  # see task_points
+        self._trailer_tasks = False  # tasks check member trailers; see task_points
         self.file_size = reader.size()
         self.total_bits = self.file_size * 8
         self.n_nominal = max(1, -(-self.file_size // chunk_size))
@@ -192,6 +210,7 @@ class ChunkFetcher:
             prefetch_cache if prefetch_cache is not None else LRUCache(2 * self.parallelization)
         )
         self.strategy = prefetch_strategy or AdaptivePrefetchStrategy(self.parallelization)
+        self._strategy_given = prefetch_strategy is not None  # see task_points
         # Stage-1 process pool (stage1_worker.start_pool), externally owned
         # like the executor: first-pass decodes of a codec a worker can
         # rebuild run there, so they stop sharing one interpreter lock.
@@ -674,20 +693,75 @@ class ChunkFetcher:
             data = data.tobytes()
         return _zlib.crc32(data) & 0xFFFFFFFF
 
+    def crc32_many(self, datas) -> Tuple[List[int], bool]:
+        """CRC32 of each of ``datas`` in one resolver request (zlib without a
+        resolver); returns the checksums and whether the device made them."""
+        if self.resolver is not None:
+            return self.resolver.crc32_many(datas)
+        return [_zlib.crc32(d) & 0xFFFFFFFF for d in datas], False
+
     # ------------------------------------------------------------------
     # indexed mode (second pass / imported index / BGZF)
     # ------------------------------------------------------------------
 
-    def _indexed_cost(self, i: int) -> int:
-        out_size = self.index.chunk_output_size(i)
-        return out_size if out_size else self.chunk_size
+    @property
+    def task_points(self) -> int:
+        """Index chunks per indexed task; indexed keys ``("ix", t)`` count
+        tasks. Where the codec's chunks carry trailers and the index came
+        from member framing (its points sit right after member headers),
+        ``_members_per_task``; else 1. Fixed once the index is finalized.
+
+        Runs are prefetched by member: the default strategy then counts
+        members, two ahead for a new stream, ramping up to
+        ``parallelization`` runs ahead for a reader that goes on, so random
+        access does not prefetch whole runs it will not read."""
+        if self._task_points is None:
+            if not self.index.finalized:
+                return 1
+            framed = len(self.index) > 0 and self.index.point_at(0).is_stream_start
+            trailers = bool(self.codec.member_trailers and framed)
+            points = self._members_per_task() if trailers else 1
+            with self._strategy_lock:
+                if trailers and not self._strategy_given:
+                    self.strategy = AdaptivePrefetchStrategy(
+                        self.parallelization * points, cold_start_full=False)
+                self._trailer_tasks = trailers
+                self._task_points = points
+        return self._task_points
+
+    def _members_per_task(self) -> int:
+        """Members one task inflates and verifies in one CRC request: as
+        many as let the ``parallelization`` tasks a sequential reader keeps
+        in flight fill one of the resolver's CRC batches
+        (``max_batch_crc_bytes``) together, counting each member at the
+        codec's largest. One, the unit of random access, without a resolver
+        that batches."""
+        batch = getattr(self.resolver, "max_batch_crc_bytes", None)
+        if not batch:
+            return 1
+        return max(1, batch // (self.parallelization * self.codec.max_member_bytes))
+
+    def _task_range(self, t: int) -> range:
+        """Index chunks that indexed task ``t`` decodes."""
+        lo = t * self.task_points
+        return range(lo, min(lo + self.task_points, len(self.index)))
+
+    def _indexed_cost(self, t: int) -> int:
+        return sum(self.index.chunk_output_size(i) or self.chunk_size
+                   for i in self._task_range(t))
 
     def get_indexed(self, i: int) -> np.ndarray:
         """Decompressed bytes of index chunk ``i`` (seek point i .. i+1)."""
+        t = i // self.task_points
         with self._strategy_lock:
-            targets = self.strategy.on_access(i)
+            targets = self.strategy.on_access(i if self._trailer_tasks else t)
+        if self._trailer_tasks:
+            # The runs holding the members asked for; run ``t`` is this
+            # read's own, fetched below.
+            targets = sorted({j // self.task_points for j in targets if j >= 0} - {t})
         for j in targets:
-            if 0 <= j < len(self.index) and self.index.chunk_output_size(j) is not None:
+            last = min((j + 1) * self.task_points, len(self.index)) - 1
+            if 0 <= j * self.task_points <= last and self.index.chunk_output_size(last) is not None:
                 with self._lock:
                     if self._live_inflight_locked(("ix", j)) is not None:
                         continue
@@ -696,27 +770,62 @@ class ChunkFetcher:
                 self._submit(("ix", j), self._task_indexed, j,
                              cost=self._indexed_cost(j), priority=False)
 
-        key = ("ix", i)
+        key = ("ix", t)
         val = self._cache_lookup(key)
-        if val is not None:
+        if val is None and self.task_points > 1:
+            with self._lock:
+                joined = self._live_inflight_locked(key) is not None
+            if not joined:
+                # A cold read its run is not on the way for (random access,
+                # or a scan's first read): the member alone answers it, and
+                # the run follows as a prefetch for a reader that goes on.
+                member = self._cache_lookup(("ixm", i))
+                if member is None:
+                    self._submit(key, self._task_indexed, t,
+                                 cost=self._indexed_cost(t), priority=False)
+                    member = self._blocking_result(("ixm", i), self._task_member, i,
+                                                   cost=self.index.chunk_output_size(i))
+                return member
+        if val is None:
+            # Blocking fetch: interactive lane (jumps this tenant's
+            # prefetches), resilient to a disconnect sweep cancelling the
+            # future it joined.
+            try:
+                val = self._blocking_result(key, self._task_indexed, t,
+                                            cost=self._indexed_cost(t))
+            except FormatError:
+                if self.task_points == 1:
+                    raise
+                # Another member of the run failed its check: this one is
+                # served if it passes its own.
+                return self._blocking_result(("ixm", i), self._task_member, i,
+                                             cost=self.index.chunk_output_size(i))
+        if self.task_points == 1:
             return val
-        # Blocking fetch: interactive lane (jumps this tenant's prefetches),
-        # resilient to a disconnect sweep cancelling the future it joined.
-        return self._blocking_result(key, self._task_indexed, i,
-                                     cost=self._indexed_cost(i))
+        lo = (self.index.point_at(i).decompressed_byte
+              - self.index.point_at(t * self.task_points).decompressed_byte)
+        return val[lo : lo + self.index.chunk_output_size(i)]
 
     def put_indexed(self, i: int, data: np.ndarray) -> None:
         """Install first-pass bytes under their index key (frontier handoff).
 
         Goes to the prefetch cache (2x parallelism entries): the access cache
-        may be sized 1 and a chunk can hand over several split slices.
+        may be sized 1 and a chunk can hand over several split slices. Only
+        codecs with a first pass hand over, and their tasks hold one chunk.
         """
         self._insert_hinted(self.prefetch_cache, ("ix", i), data,
                             recompute_cost=int(data.nbytes))
 
-    def _task_indexed(self, i: int) -> np.ndarray:
+    def _task_indexed(self, t: int) -> np.ndarray:
         with self._lock:
             self.stats.indexed_tasks += 1
+        sp = _task_span.get() if _obs_trace.tracing_enabled() else None
+        if sp is not None:
+            # Trailer-carrying members the task inflates and checks.
+            sp.set_attr("members", len(self._task_range(t)) if self._trailer_tasks else 0)
+        if self._trailer_tasks:
+            return self._verified_members(self._task_range(t), ("ix", t))
+        i = t
         point = self.index.point_at(i)
         out_size = self.index.chunk_output_size(i)
         if out_size is None:
@@ -772,6 +881,65 @@ class ChunkFetcher:
         # is a single delegation over out_size bytes.
         self._insert_hinted(self.prefetch_cache, ("ix", i), data,
                             recompute_cost=out_size)
+        return data
+
+    def _task_member(self, i: int) -> np.ndarray:
+        """Index chunk ``i``'s member alone, checked like a run of them."""
+        with self._lock:
+            self.stats.indexed_tasks += 1
+        sp = _task_span.get() if _obs_trace.tracing_enabled() else None
+        if sp is not None:
+            sp.set_attr("members", 1)
+        return self._verified_members(range(i, i + 1), ("ixm", i))
+
+    def _verified_members(self, members: range, key) -> np.ndarray:
+        """Inflate ``members`` and check each one's trailer before any of its
+        bytes is cached (under ``key``) or served: ISIZE against the inflated
+        length, and (under ``verify``) CRC32 through the resolver, all the
+        members in one request. A mismatch raises GzipFooterError."""
+        starts = [self.index.point_at(i).compressed_bit // 8 for i in members]
+        stop = (self.index.point_at(members.stop).compressed_bit // 8
+                if members.stop < len(self.index) else self.file_size)
+        buf, base = self._buffer(starts[0], stop)
+        bounds = [b - base for b in starts] + [stop - base]
+        bodies: List[bytes] = []
+        trailers: List[int] = []
+        try:
+            for k, i in enumerate(members):
+                size = self.index.chunk_output_size(i)
+                body, crc, isize = self.codec.inflate_member(buf, bounds[k], bounds[k + 1], size)
+                if not len(body) == isize == size:
+                    raise GzipFooterError(
+                        "ISIZE mismatch in the member at decompressed offset %d"
+                        % self.index.point_at(i).decompressed_byte)
+                bodies.append(body)
+                trailers.append(crc)
+        except GzipFooterError:
+            with self._lock:
+                self.stats.member_crc_mismatches += 1
+            raise
+        nbytes = sum(len(b) for b in bodies)
+        with self._lock:
+            self.stats.zlib_delegations += len(bodies)
+        if self.verify:
+            with _obs_trace.span("fetcher.member_verify", {"members": len(bodies), "bytes": nbytes}):
+                crcs, on_device = self.crc32_many(bodies)
+            bad = [k for k, (got, want) in enumerate(zip(crcs, trailers)) if got != want]
+            with self._lock:
+                self.stats.members_verified += len(bodies)
+                self.stats.member_crc_mismatches += len(bad)
+                if on_device:
+                    self.stats.member_crc_device_bytes += nbytes
+            if bad:
+                raise GzipFooterError(
+                    "CRC32 mismatch in the member at decompressed offset %d"
+                    % self.index.point_at(members[bad[0]]).decompressed_byte)
+        data = np.frombuffer(b"".join(bodies), np.uint8)
+        with self._lock:
+            # Every inflate counts, a re-decode of an evicted task too, as
+            # every inflate's CRC is computed again.
+            self.stats.bytes_decompressed += nbytes
+        self._insert_hinted(self.prefetch_cache, key, data, recompute_cost=nbytes)
         return data
 
     # ------------------------------------------------------------------

@@ -63,6 +63,14 @@ A ``Codec`` must provide:
     Seek-point flag mask for which ``delegate`` is invalid and
     ``decode_chunk`` must be used (deflate: interior member ends, shift-
     broken stored blocks).
+``member_trailers`` / ``inflate_member(buf, start, stop, size)``
+    True where every chunk of the exact index (points flagged
+    ``FLAG_STREAM_START``) is one whole gzip member ending in its own CRC32
+    and ISIZE (BGZF). The fetcher then inflates such chunks with
+    ``inflate_member``, which returns the body and the trailer's two
+    fields, and checks both before the bytes are cached or served.
+    ``max_member_bytes`` bounds what one such member inflates to; the
+    fetcher sizes its runs of members by it.
 ``propagate_window(data, window)`` / ``replace_markers(data, window)``
     Stage-2 marker machinery; windowless codecs inherit the no-op defaults.
 ``set_stage2_resolver(resolver)``
@@ -112,6 +120,7 @@ codec          seek point sits at     chunk payload              window
 from __future__ import annotations
 
 import struct
+import zlib
 from typing import Iterable, Iterator, List, Optional, Tuple, Union
 
 import numpy as np
@@ -126,7 +135,7 @@ from .deflate import (
     DeflateChunkDecoder,
     canonical_stored_offset,
 )
-from .errors import FormatError, GzipHeaderError
+from .errors import DeflateError, FormatError, GzipFooterError, GzipHeaderError
 from .gzip_format import parse_gzip_header, scan_bgzf_members
 from .index import (
     FLAG_STORED_BLOCK,
@@ -153,6 +162,10 @@ class Codec:
     verifies_members: bool = False
     #: seek-point flags that force decode_chunk over delegate
     decoder_required_flags: int = 0
+    #: every index chunk is a whole member with its own CRC32/ISIZE trailer
+    member_trailers: bool = False
+    #: the most bytes such a member inflates to
+    max_member_bytes: int = 0
     #: optional stage-2 resolver (duck-typed: ``replace_markers``/``crc32``,
     #: e.g. ``kernels.engine.DeviceDecodeEngine``); None = host CPU path.
     stage2_resolver = None
@@ -418,10 +431,13 @@ class BgzfCodec(DeflateCodec):
     one finalized seek point per member — a cold open does zero speculative
     decoding and zero marker passes. Decoding inherits deflate (a BGZF
     member body is a raw deflate stream; seek points are byte-aligned with
-    empty windows, so every chunk is zlib-delegable).
+    empty windows, so every chunk is zlib-delegable). Each chunk ends in
+    its member's trailer, which the fetcher checks (``member_trailers``).
     """
 
     tag = "bgzf"
+    member_trailers = True
+    max_member_bytes = 1 << 16  # SAM/BAM specification §4.1
 
     def __init__(self):
         super().__init__(framing="gzip")
@@ -451,13 +467,40 @@ class BgzfCodec(DeflateCodec):
             footer = reader.pread(offset + size - 8, 8)
             isize = int.from_bytes(footer[4:8], "little")
             if isize == 0:
-                continue  # BGZF EOF marker block
+                # BGZF EOF marker block. It has no point, so no read ever
+                # inflates it: a member whose ISIZE was damaged to 0 must not
+                # drop out of the stream unseen.
+                body = reader.pread(offset + hdr.header_bits // 8, size)
+                try:
+                    empty = not zlib.decompressobj(-zlib.MAX_WBITS).decompress(body, 1)
+                except zlib.error as exc:
+                    raise DeflateError("BGZF member at byte %d: %s" % (offset, exc)) from exc
+                if not empty:
+                    raise GzipFooterError("ISIZE 0 on a non-empty member at byte %d" % offset)
+                continue
             index.add_point(
                 SeekPoint(offset * 8 + hdr.header_bits, out, b"", FLAG_STREAM_START)
             )
             out += isize
         index.finalize(out, reader.size())
         return True
+
+    def inflate_member(self, buf, start: int, stop: int, size: int) -> Tuple[bytes, int, int]:
+        """Inflate the member body at byte ``start`` of ``buf``, which ends
+        with its trailer before ``stop``; returns the body and the trailer's
+        CRC32 and ISIZE. A body longer than ``size`` bytes is an ISIZE
+        mismatch, found after inflating one byte more than ``size``."""
+        d = zlib.decompressobj(-zlib.MAX_WBITS)
+        try:
+            body = d.decompress(memoryview(buf)[start:stop], size + 1)
+        except zlib.error as exc:
+            raise DeflateError("BGZF member at byte %d: %s" % (start, exc)) from exc
+        if len(body) > size:
+            raise GzipFooterError("ISIZE mismatch: member at byte %d inflates past %d bytes" % (start, size))
+        if not d.eof or len(d.unused_data) < 8:
+            raise DeflateError("BGZF member at byte %d is truncated" % start)
+        crc, isize = struct.unpack("<II", d.unused_data[:8])
+        return body, crc, isize
 
     def seek_hostility(self, index: GzipIndex) -> float:
         # Inherits DeflateCodec, but a BGZF index comes from framing

@@ -63,26 +63,38 @@ def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
     return (shifted ^ crc2) & 0xFFFFFFFF
 
 
+@functools.lru_cache(maxsize=64)
+def _zeros_table(nbytes: int) -> np.ndarray:
+    """``_zeros_operator(nbytes)`` as four 256-entry tables, one per byte of
+    the CRC: the operator is linear, so its image of a CRC is the XOR of the
+    images of the CRC's four bytes."""
+    op = np.array(_zeros_operator(nbytes), np.uint32).reshape(4, 8)
+    bits = ((np.arange(256)[:, None] >> np.arange(8)) & 1).astype(np.uint32)  # (256, 8)
+    table = np.zeros((4, 256), np.uint32)
+    for j in range(8):
+        table ^= bits[:, j] * op[:, j : j + 1]
+    return table
+
+
 def combine_lanes(crcs: np.ndarray, lane_len: int) -> np.ndarray:
     """Fold each row of equal-length lane CRCs left to right, vectorized.
 
     ``crcs`` is ``(rows, lanes)`` with ``lanes`` a power of two and every
     lane ``lane_len`` bytes long; returns ``(rows,)`` uint32. A pairwise
     tree: level ``k`` merges neighbours of ``lane_len * 2**k`` bytes with
-    one cached operator, 32 NumPy passes per level. A lane holding 0 folds
-    in as the CRC of an empty string, so callers right-align short rows.
+    one cached operator, applied a byte at a time by table. A lane holding 0
+    folds in as the CRC of an empty string, so callers right-align short
+    rows.
     """
     arr = np.asarray(crcs, np.uint32)
     if arr.shape[1] & (arr.shape[1] - 1):
         raise ValueError("lane count must be a power of two")
     n = lane_len
     while arr.shape[1] > 1:
-        op = _zeros_operator(n)
+        t = _zeros_table(n)
         left, right = arr[:, 0::2], arr[:, 1::2]
-        shifted = np.zeros_like(left)
-        for i in range(32):
-            shifted ^= ((left >> np.uint32(i)) & np.uint32(1)) * np.uint32(op[i])
-        arr = shifted ^ right
+        arr = (t[0][left & 0xFF] ^ t[1][(left >> 8) & 0xFF]
+               ^ t[2][(left >> 16) & 0xFF] ^ t[3][left >> 24] ^ right)
         n *= 2
     return arr[:, 0]
 

@@ -142,6 +142,7 @@ class ParallelGzipReader(io.RawIOBase):
                 prefetch_strategy=prefetch_strategy,
                 resolver=resolver,
                 stage1_pool=stage1_pool,
+                verify=verify,
             )
             self._index = self._fetcher.index
 

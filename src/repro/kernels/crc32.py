@@ -14,6 +14,12 @@ lookup table and no gather (Mosaic lowers no general gather from VMEM).
 The grid is ``(batch, seg_words // block_words)``: the second axis walks a
 long segment in VMEM-sized blocks and carries the CRC state in the output
 block, so the block size, not the request size, bounds VMEM.
+
+The engine lays every part of a batch into the lanes of one row
+(``pack_parts``), each part in whole lanes of its own, so a dispatch has
+batch 1 and the compiled shapes are one per ``seg_words``. The host writes
+the row lane-major, ``(1024, seg_words)``, the parts' bytes in order with no
+transposing copy; ``crc32_lanes`` turns it words-major on the device.
 """
 
 from __future__ import annotations
@@ -66,7 +72,7 @@ def crc32_segments_batched(data: jax.Array, *, interpret: bool = False) -> jax.A
 
     data: (batch, seg_words, SEG_ROWS, SEG_COLS) int32 — word ``w`` of lane
           ``(r, c)`` holds bytes ``[4w, 4w + 4)`` of that lane's segment,
-          little-endian (``pack_lanes`` lays a request out this way).
+          little-endian (``crc32_lanes`` lays ``pack_parts``' rows out this way).
     returns (batch, SEG_ROWS, SEG_COLS) int32 CRCs, one per lane.
     """
     batch, seg_words = data.shape[:2]
@@ -91,7 +97,20 @@ def crc32_segments_batched(data: jax.Array, *, interpret: bool = False) -> jax.A
     )(data)
 
 
-# -- host side: lane packing and the fold back to one CRC per request ---------
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def crc32_lanes(lanes: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """``crc32_segments_batched`` of lane-major rows.
+
+    lanes: (batch, 1024, seg_words) int32 — lane ``s`` of a row holds its
+           segment's ``seg_words`` words in order (``pack_parts``).
+    returns (batch, SEG_ROWS, SEG_COLS) int32 CRCs, one per lane.
+    """
+    batch, _, seg_words = lanes.shape
+    words = jnp.transpose(lanes, (0, 2, 1)).reshape(batch, seg_words, SEG_ROWS, SEG_COLS)
+    return crc32_segments_batched(words, interpret=interpret)
+
+
+# -- host side: lane packing and the fold back to one CRC per part ------------
 
 def lane_words(nbytes: int) -> int:
     """Words per lane (a power of two) so that 1024 lanes hold ``nbytes``."""
@@ -99,45 +118,68 @@ def lane_words(nbytes: int) -> int:
     return 1 << (need - 1).bit_length()
 
 
-def pack_lanes(row: np.ndarray, data: bytes) -> None:
-    """Lay ``data``'s whole segments into one ``(seg_words, 8, 128)`` row.
+def _lanes(nbytes: int, seg_len: int) -> int:
+    return -(-nbytes // seg_len)
 
-    Lane ``s`` gets bytes ``[s * seg_len, (s + 1) * seg_len)``. Lanes past
-    the last whole segment keep whatever ``row`` held: ``finish_crcs``
-    ignores them and CRCs the ragged tail on the host.
+
+def parts_words(sizes: Sequence[int]) -> int:
+    """Words per lane (a power of two) at which the 1024 lanes of one row hold
+    every part in whole lanes of its own."""
+    if len(sizes) > N_SEGMENTS:
+        raise ValueError("%d parts overflow %d lanes" % (len(sizes), N_SEGMENTS))
+    words = lane_words(sum(sizes))
+    while sum(_lanes(n, words * WORD_BYTES) for n in sizes) > N_SEGMENTS:
+        words *= 2
+    return words
+
+
+def pack_parts(row: np.ndarray, datas: Sequence[bytes]) -> None:
+    """Lay the parts into one lane-major ``(1024, seg_words)`` row, one after
+    another.
+
+    Each part takes whole lanes, right-aligned, with zeros in front of it in
+    its first lane: lane ``s`` holds bytes ``[s * seg_len, (s + 1) * seg_len)``
+    of the padded parts laid end to end, so packing is one copy per part.
+    Every byte of every part is CRC'd on the device; ``finish_parts`` takes
+    the leading zeros back out. Lanes past the last part keep whatever
+    ``row`` held.
     """
-    seg_words = row.shape[0]
-    seg_len = seg_words * WORD_BYTES
-    full = len(data) // seg_len
-    if full > N_SEGMENTS:
-        raise ValueError("%d bytes overflow %d lanes of %d" % (len(data), N_SEGMENTS, seg_len))
-    if full:
-        words = np.frombuffer(data, "<u4", count=full * seg_words)
-        row.reshape(seg_words, N_SEGMENTS).view(np.uint32)[:, :full] = (
-            words.reshape(full, seg_words).T
-        )
+    seg_len = row.shape[1] * WORD_BYTES
+    flat = row.reshape(-1).view(np.uint8)
+    pos = 0
+    for data in datas:
+        end = pos + _lanes(len(data), seg_len) * seg_len
+        flat[pos : end - len(data)] = 0
+        flat[end - len(data) : end] = np.frombuffer(data, np.uint8)
+        pos = end
 
 
-def finish_crcs(
-    lane_crcs: np.ndarray, datas: Sequence[bytes], seg_words: int
-) -> List[int]:
-    """Fold each request's lane CRCs (plus its ragged tail) into its CRC32.
+def finish_parts(lane_crcs: np.ndarray, sizes: Sequence[int], seg_words: int) -> List[int]:
+    """Fold one row's lane CRCs (``(8, 128)`` kernel output of a row packed by
+    ``pack_parts``) into the CRC32 of each part.
 
-    lane_crcs: (>= len(datas), SEG_ROWS, SEG_COLS) kernel output.
+    A part's lanes fold to the CRC of its zero-padded form ``Z + M``;
+    ``crc(Z + M) = shift(crc(Z), len(M)) ^ crc(M)``, so the CRC of ``M`` is
+    that fold with ``crc32_combine(crc(Z), 0, len(M))`` XORed back out.
     """
+    if not sizes:
+        return []
+    flat = np.asarray(lane_crcs).reshape(N_SEGMENTS).view(np.uint32)
     seg_len = seg_words * WORD_BYTES
-    rows = np.zeros((len(datas), N_SEGMENTS), np.uint32)
-    flat = lane_crcs.reshape(lane_crcs.shape[0], N_SEGMENTS).view(np.uint32)
-    for i, data in enumerate(datas):
-        full = len(data) // seg_len
-        if full:
-            # Whole lanes right-aligned: a leading 0 folds in as the CRC of
-            # an empty prefix, so the tree fold needs no per-request length.
-            rows[i, N_SEGMENTS - full :] = flat[i, :full]
+    lanes = [_lanes(n, seg_len) for n in sizes]
+    width = 1 << max(0, max(lanes) - 1).bit_length()
+    rows = np.zeros((len(sizes), width), np.uint32)
+    pos = 0
+    for i, n in enumerate(lanes):
+        # Right-aligned: leading 0s fold in as empty prefixes.
+        rows[i, width - n :] = flat[pos : pos + n]
+        pos += n
     out = []
-    for data, crc in zip(datas, combine_lanes(rows, seg_len)):
-        tail = data[(len(data) // seg_len) * seg_len :]
-        out.append(
-            crc32_combine(int(crc), _zlib.crc32(tail) & 0xFFFFFFFF, len(tail))
-        )
+    fixes = {}  # (pad, n) -> shift(crc(Z), n); a batch repeats a few sizes
+    for n, count, crc in zip(sizes, lanes, combine_lanes(rows, seg_len)):
+        pad = count * seg_len - n
+        fix = fixes.get((pad, n))
+        if fix is None:
+            fix = fixes[pad, n] = crc32_combine(_zlib.crc32(bytes(pad)), 0, n)
+        out.append(int(crc) ^ fix)
     return out

@@ -23,7 +23,9 @@ Layout and policy:
   * **Shape bucketing** — tile counts and table counts round up to powers of
     two (capped at ``max_batch_tiles``), so the jitted dispatches compile a
     bounded set of shapes once and are reused forever (cached compiled
-    kernels). The CRC path buckets ``seg_len`` the same way.
+    kernels). A CRC batch lays all its parts into the lanes of one row
+    (``crc32.pack_parts``), so its shape is ``seg_len`` alone, a power of
+    two: whatever the number of requests, one shape per size class.
   * **Double-buffered staging** — two host staging buffers per bucket shape
     alternate between dispatches, and result readback of batch N overlaps
     the launch of batch N+1 (one dispatch in flight), so host packing and
@@ -63,12 +65,11 @@ import numpy as np
 from ..core.markers import replace_markers as _cpu_replace_markers
 from ..obs import trace as _obs_trace
 from .crc32 import (
-    SEG_COLS,
-    SEG_ROWS,
-    crc32_segments_batched,
-    finish_crcs,
-    lane_words,
-    pack_lanes,
+    N_SEGMENTS,
+    crc32_lanes,
+    finish_parts,
+    pack_parts,
+    parts_words,
 )
 from .marker_replace import (
     TABLE_SIZE,
@@ -173,18 +174,22 @@ def load_crossover(root: Optional[str] = None) -> Dict[str, Optional[int]]:
 
 
 class _Request:
-    __slots__ = ("kind", "symbols", "window", "data", "tiles", "nbytes", "future")
+    """One queued request: a marker stream, or the byte strings (``parts``)
+    whose CRC32s one future resolves to (a list, or an int for ``crc32``)."""
 
-    def __init__(self, kind: str, *, symbols=None, window=None, data=None):
+    __slots__ = ("kind", "symbols", "window", "parts", "many", "tiles", "nbytes", "future")
+
+    def __init__(self, kind: str, *, symbols=None, window=None, parts=(), many=False):
         self.kind = kind
         self.symbols = symbols
         self.window = window
-        self.data = data
+        self.parts = parts
+        self.many = many
         if kind == "replace":
             self.nbytes = int(symbols.shape[0])
             self.tiles = max(1, -(-self.nbytes // TILE))
         else:
-            self.nbytes = len(data)
+            self.nbytes = sum(len(p) for p in parts)
             self.tiles = 0
         self.future: Future = Future()
 
@@ -197,8 +202,10 @@ class DeviceDecodeEngine:
     resolver surface consumed by ``core.codec`` / ``core.chunk_fetcher``:
 
       * ``replace_markers(symbols, window) -> np.uint8 ndarray`` (blocking)
-      * ``crc32(data) -> int`` (blocking)
-      * ``submit_replace`` / ``submit_crc`` -> Future (async variants)
+      * ``crc32(data) -> int`` and ``crc32_many(datas) -> (crcs, on_device)``
+        (blocking)
+      * ``submit_replace`` / ``submit_crc`` / ``submit_crcs`` -> Future
+        (async variants)
       * ``stats() -> dict`` / ``shutdown()``
     """
 
@@ -217,6 +224,8 @@ class DeviceDecodeEngine:
         self.max_batch_tiles = max(1, max_batch_tiles)
         self.max_tables = _pow2_at_least(max(1, max_tables))
         self.max_batch_crc_bytes = max(1 << 10, max_batch_crc_bytes)
+        # Requests per CRC batch; their parts together fit the row's 1024
+        # lanes and their bytes ``max_batch_crc_bytes``.
         self.max_crc_requests = max(1, max_crc_requests)
         self.max_delay_s = max(0.0, max_delay_s)
         self.force_device = force_device
@@ -306,13 +315,22 @@ class DeviceDecodeEngine:
 
     def submit_crc(self, data) -> Future:
         """Queue a CRC32 request; resolves to the int checksum."""
+        return self._submit_crc([_as_bytes(data)], many=False)
+
+    def submit_crcs(self, datas: Sequence) -> Future:
+        """Queue one request for the CRC32 of each of ``datas`` (at most 1024
+        parts); resolves to the list of checksums. The parts dispatch
+        together, with those of other requests queued beside them."""
+        return self._submit_crc([_as_bytes(d) for d in datas], many=True)
+
+    def _submit_crc(self, parts: List[bytes], *, many: bool) -> Future:
+        if len(parts) > N_SEGMENTS:
+            raise ValueError("%d parts in one CRC request; at most %d" % (len(parts), N_SEGMENTS))
         self._count(self._requests, "crc")
-        data = _as_bytes(data)
-        fut: Future = Future()
-        if len(data) == 0:
-            fut.set_result(0)
-            return fut
-        req = _Request("crc", data=data)
+        req = _Request("crc", parts=parts, many=many)
+        if req.nbytes == 0:
+            req.future.set_result([0] * len(parts) if many else 0)
+            return req.future
         self._enqueue(self._cq, req)
         return req.future
 
@@ -351,25 +369,45 @@ class DeviceDecodeEngine:
 
     def crc32(self, data) -> int:
         """CRC32 — batched on-device above the crossover, zlib below it."""
-        data = _as_bytes(data)
-        if self._route_device("crc", len(data)):
+        return self.crc32_many([data])[0][0]
+
+    def crc32_many(self, datas: Sequence) -> Tuple[List[int], bool]:
+        """CRC32 of each of ``datas`` as one request, routed by their total
+        size like ``crc32``; returns the checksums and whether the device
+        computed them."""
+        datas = [_as_bytes(d) for d in datas]
+        nbytes = sum(len(d) for d in datas)
+        if self._route_device("crc", nbytes):
             try:
-                fut = self.submit_crc(data)
-                with _obs_trace.timed("engine.batch_wait", {"kind": "crc", "nbytes": len(data)}):
-                    return fut.result()
+                fut = self.submit_crcs(datas)
+                with _obs_trace.timed("engine.batch_wait", {"kind": "crc", "nbytes": nbytes}):
+                    return fut.result(), True
             except EngineClosedError:
                 pass
         else:
             self._count(self._requests, "crc")
         self._count(self._fallbacks, "crc")
-        return _zlib.crc32(data) & 0xFFFFFFFF
+        return [_zlib.crc32(d) & 0xFFFFFFFF for d in datas], False
 
     # ------------------------------------------------------------------
     # dispatcher thread
     # ------------------------------------------------------------------
 
-    def _collect_batch(self) -> Optional[Tuple[List[_Request], List[_Request]]]:
-        """Block until work (or shutdown); return one coalesced batch.
+    def _crc_full(self) -> bool:
+        """The queued CRC requests fill a batch: as many requests as one may
+        hold, every lane taken, or no room for one more like the largest."""
+        if not self._cq:
+            return False
+        return (
+            len(self._cq) >= self.max_crc_requests
+            or sum(len(r.parts) for r in self._cq) >= N_SEGMENTS
+            or sum(r.nbytes for r in self._cq) + max(r.nbytes for r in self._cq)
+            > self.max_batch_crc_bytes
+        )
+
+    def _collect_batch(self) -> Optional[Tuple[List[_Request], List[_Request], float]]:
+        """Block until work (or shutdown); return one coalesced batch and the
+        seconds spent waiting for it to fill.
 
         After the first request arrives, waits up to ``max_delay_s`` for the
         batch to fill — the window in which concurrent readers' stage-2 work
@@ -380,16 +418,12 @@ class DeviceDecodeEngine:
                 self._cond.wait()
             if self._closed:
                 return None
+            t_fill = time.monotonic()
             if self.max_delay_s > 0.0:
-                deadline = time.monotonic() + self.max_delay_s
+                deadline = t_fill + self.max_delay_s
                 while not self._closed:
                     tiles = sum(r.tiles for r in self._rq)
-                    crc_bytes = sum(r.nbytes for r in self._cq)
-                    if (
-                        tiles >= self.max_batch_tiles
-                        or len(self._cq) >= self.max_crc_requests
-                        or crc_bytes >= self.max_batch_crc_bytes
-                    ):
+                    if tiles >= self.max_batch_tiles or self._crc_full():
                         break
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
@@ -397,6 +431,7 @@ class DeviceDecodeEngine:
                     self._cond.wait(remaining)
                 if self._closed:
                     return None
+            fill_s = time.monotonic() - t_fill
 
             rep: List[_Request] = []
             tiles = 0
@@ -415,15 +450,20 @@ class DeviceDecodeEngine:
                 tiles += req.tiles
                 tables.add(key)
             crc: List[_Request] = []
-            crc_bytes = 0
-            while self._cq and len(crc) < self.max_crc_requests:
+            crc_parts = crc_bytes = 0
+            while self._cq:
                 req = self._cq[0]
-                if crc and crc_bytes + req.nbytes > self.max_batch_crc_bytes:
+                if crc and (
+                    len(crc) >= self.max_crc_requests
+                    or crc_parts + len(req.parts) > N_SEGMENTS
+                    or crc_bytes + req.nbytes > self.max_batch_crc_bytes
+                ):
                     break
                 self._cq.popleft()
                 crc.append(req)
+                crc_parts += len(req.parts)
                 crc_bytes += req.nbytes
-            return rep, crc
+            return rep, crc, fill_s
 
     def _worker_loop(self) -> None:
         pending = None  # resolve-callback of the previous (in-flight) batch
@@ -431,13 +471,13 @@ class DeviceDecodeEngine:
             batch = self._collect_batch()
             if batch is None:
                 break
-            rep, crc = batch
+            rep, crc, fill_s = batch
             launched = []
             try:
                 if rep:
                     launched.append(self._dispatch_replace(rep))
                 if crc:
-                    launched.append(self._dispatch_crc(crc))
+                    launched.append(self._dispatch_crc(crc, fill_s))
             except BaseException as exc:  # noqa: BLE001 - fail the batch, keep serving
                 with self._cond:
                     self._errors += 1
@@ -601,30 +641,51 @@ class DeviceDecodeEngine:
 
     # -- CRC dispatch ----------------------------------------------------
 
-    def _dispatch_crc(self, reqs: List[_Request]):
-        """Pack many byte streams into one (B, seg_words, 8, 128) dispatch.
+    def _dispatch_crc(self, reqs: List[_Request], fill_s: float = 0.0):
+        """Pack every part of every request into one lane-major
+        (1, 1024, seg_words) dispatch, each part in whole lanes of its own.
 
-        Returns ``(resolve, reqs)`` like ``_dispatch_replace``.
+        Returns ``(resolve, reqs)`` like ``_dispatch_replace``. Under tracing
+        the dispatcher's host work shows as an ``engine.dispatch`` span
+        (packing, upload and launch; the batch's fill wait before it as an
+        attribute) and an ``engine.resolve`` span (readback, fold and
+        answering the futures).
         """
-        seg_words = lane_words(max(r.nbytes for r in reqs))
-        batch = _pow2_at_least(len(reqs))
-        stage = self._staging_buffer(
-            ("crc", batch, seg_words), (batch, seg_words, SEG_ROWS, SEG_COLS)
-        )
-        for row, req in zip(stage, reqs):
-            pack_lanes(row, req.data)
-        out = crc32_segments_batched(
-            jax.device_put(stage, self.device), interpret=self.interpret
-        )
+        tracing = _obs_trace.tracing_enabled()
+        t0 = time.perf_counter()
+        c0 = time.thread_time() if tracing else 0.0
+        parts = [p for r in reqs for p in r.parts]
+        sizes = [len(p) for p in parts]
+        seg_words = parts_words(sizes)
+        stage = self._staging_buffer(("crc", seg_words), (1, N_SEGMENTS, seg_words))
+        pack_parts(stage[0], parts)
+        t1 = time.perf_counter()
+        out = crc32_lanes(jax.device_put(stage, self.device), interpret=self.interpret)
         with self._cond:
             self._dispatches += 1
-            self._crc_bytes += sum(r.nbytes for r in reqs)
+            self._crc_bytes += sum(sizes)
+        if tracing:
+            t2 = time.perf_counter()
+            _obs_trace.record_span("engine.dispatch", t0, t2 - t0, {
+                "kind": "crc", "requests": len(reqs), "parts": len(parts),
+                "bytes": sum(sizes), "seg_words": seg_words, "fill_s": fill_s,
+                "pack_s": t1 - t0, "launch_s": t2 - t1, "cpu_s": time.thread_time() - c0})
 
         def resolve() -> None:
-            crcs = finish_crcs(np.asarray(out), [r.data for r in reqs], seg_words)
-            for req, crc in zip(reqs, crcs):
+            r0 = time.perf_counter()
+            rc0 = time.thread_time() if tracing else 0.0
+            lane_crcs = np.asarray(out)[0]
+            r1 = time.perf_counter()
+            crcs = iter(finish_parts(lane_crcs, sizes, seg_words))
+            for req in reqs:
+                got = [next(crcs) for _ in req.parts]
                 if not req.future.done():
-                    req.future.set_result(crc)
+                    req.future.set_result(got if req.many else got[0])
+            if tracing:
+                r2 = time.perf_counter()
+                _obs_trace.record_span("engine.resolve", r0, r2 - r0, {
+                    "kind": "crc", "requests": len(reqs), "readback_s": r1 - r0,
+                    "fold_s": r2 - r1, "cpu_s": time.thread_time() - rc0})
 
         return resolve, reqs
 

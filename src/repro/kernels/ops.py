@@ -18,12 +18,11 @@ import jax
 import numpy as np
 
 from .crc32 import (
-    SEG_COLS,
-    SEG_ROWS,
-    crc32_segments_batched,
-    finish_crcs,
+    N_SEGMENTS,
+    crc32_lanes,
+    finish_parts,
     lane_words,
-    pack_lanes,
+    pack_parts,
 )
 from .marker_replace import TILE, TILE_COLS, TILE_ROWS, marker_replace_tiles_multi
 from .precode_check import BLOCK, HALO, ROWS, precode_check_blocks
@@ -100,9 +99,9 @@ def crc32_parallel(data: bytes) -> int:
         return 0
     device = jax.devices()[0]
     seg_words = lane_words(len(data))
-    stage = np.zeros((1, seg_words, SEG_ROWS, SEG_COLS), dtype=np.int32)
-    pack_lanes(stage[0], data)
-    lanes = crc32_segments_batched(
+    stage = np.zeros((1, N_SEGMENTS, seg_words), dtype=np.int32)
+    pack_parts(stage[0], [data])
+    lanes = crc32_lanes(
         jax.device_put(stage, device), interpret=interpret_on(device)
     )
-    return finish_crcs(np.asarray(lanes), [data], seg_words)[0]
+    return finish_parts(np.asarray(lanes)[0], [len(data)], seg_words)[0]

@@ -4,7 +4,7 @@ Covers the engine's whole contract surface:
   * bit-identical parity vs the host reference under interpret=True,
     including ragged last tiles, empty chunks, and >1-slab requests;
   * coalescing of interleaved multi-tenant submissions into shared batches;
-  * CRC parity (device lanes + GF(2) combine + ragged host tail) vs zlib;
+  * CRC parity (device lanes + GF(2) combine, zero-padded first lanes) vs zlib;
   * crossover routing (small/singleton requests take the CPU path and are
     counted as fallbacks) and the derive_crossover math itself;
   * shutdown-while-queued and readback failures — futures error, never hang;

@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.crc32 import BLOCK_WORDS, SEG_COLS, SEG_ROWS, crc32_segments_batched
+from repro.kernels.crc32 import BLOCK_WORDS, SEG_COLS, SEG_ROWS, crc32_lanes
 from repro.kernels.engine import DeviceDecodeEngine
 from repro.kernels.marker_replace import TILE_COLS, TILE_ROWS, marker_replace_tiles_multi
 from repro.kernels.precode_check import BLOCK, ROWS, precode_check_blocks
@@ -91,12 +91,13 @@ def test_marker_replace_compiles_for_v5e(one_chip):
 
 
 def test_crc32_compiles_for_v5e(one_chip):
+    # The engine lays a whole batch into the lanes of one lane-major row
+    # (batch 1), which the program turns words-major on the device.
     seg_words = _ENGINE["max_batch_crc_bytes"] // (SEG_ROWS * SEG_COLS * 4)
-    batch = _ENGINE["max_crc_requests"]
     compiled = _compile(
-        lambda d: crc32_segments_batched(d, interpret=False),
+        lambda d: crc32_lanes(d, interpret=False),
         one_chip,
-        (batch, seg_words, SEG_ROWS, SEG_COLS),
+        (1, SEG_ROWS * SEG_COLS, seg_words),
     )
     assert _is_pallas(compiled)
     # Double-buffered input block plus the resident output block.
