@@ -94,6 +94,7 @@ class FetcherStats:
     zlib_delegations: int = 0
     bytes_decompressed: int = 0  # first pass finalized; trailer members inflated
     stage1_offloaded: int = 0  # nominal and exact tasks decoded in the stage-1 pool
+    stage1_native_bytes: int = 0  # of their results' bytes, those zlib decoded
     members_verified: int = 0  # trailer members whose CRC32 and ISIZE were checked
     member_crc_mismatches: int = 0  # trailer members whose CRC32 or ISIZE did not match
     member_crc_device_bytes: int = 0  # bytes of those members CRC'd on the device
@@ -552,6 +553,17 @@ class ChunkFetcher:
             sp.attrs["cpu_s"] += cpu_s
         return value
 
+    def _count_stage1(self, result: DecodeResult) -> None:
+        """Count a first-pass result: whether it holds markers, and the
+        bytes zlib decoded of it (on the task's span too, under tracing)."""
+        with self._lock:
+            if result.contains_markers():
+                self.stats.chunks_with_markers += 1
+            self.stats.stage1_native_bytes += result.native_bytes
+        sp = _task_span.get() if _obs_trace.tracing_enabled() else None
+        if sp is not None:
+            sp.set_attr("native_bytes", result.native_bytes)
+
     def _task_nominal(self, k: int) -> Optional[DecodeResult]:
         if not self.codec.supports_speculation:
             # Exact-index codecs (BGZF, zstd) never speculate: the reader
@@ -602,9 +614,7 @@ class ChunkFetcher:
                 self.prefetch_cache, ("fp", result.start_bit), result,
                 recompute_cost=self._result_cost(result),
             )
-            with self._lock:
-                if result.contains_markers():
-                    self.stats.chunks_with_markers += 1
+            self._count_stage1(result)
         return result
 
     def _task_exact(self, bit_offset: int, window: Optional[bytes]) -> DecodeResult:
@@ -632,8 +642,7 @@ class ChunkFetcher:
             )
             with self._lock:
                 self._nominal_done.setdefault(k, res.start_bit)
-                if res.contains_markers():
-                    self.stats.chunks_with_markers += 1
+            self._count_stage1(res)
             return res
         raise last_err  # pragma: no cover - loop always ends at EOF
 

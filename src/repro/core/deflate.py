@@ -12,8 +12,15 @@ onto the TPU VPU.
 
 When the window *is* known (seek-index hit, or stream start where the window
 is empty) the decoder runs in conventional single-stage mode straight to
-uint8. Mid-chunk, the decoder tracks the last marker position so callers can
-see when output became marker-free (paper §3.3's fallback optimization).
+uint8. Mid-chunk, the decoder tracks the last marker position, and once the
+32 KiB before a block hold no marker the window for the rest of the chunk is
+known (no distance reaches further back). From that block on — from the
+first block in window mode — block bodies go to zlib
+(``zlib_bridge.BlockInflater``), the paper's §3.3 fallback optimisation.
+Control returns here at every block end, so the stop rule, the ``blocks``
+list, gzip footers and headers and the marker bookkeeping stay in this
+loop; ``DecodeResult.native_bytes`` counts what zlib produced. Where the
+system zlib cannot be loaded, every block is decoded in Python.
 
 The stop condition mirrors rapidgzip exactly: decoding continues until a
 block that (a) starts at or after the stop offset, (b) is a Dynamic or
@@ -29,6 +36,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from . import zlib_bridge
 from .bitreader import BitReader
 from .errors import DeflateError, EndOfStream, GzipFooterError
 from .gzip_format import parse_gzip_footer, parse_gzip_header
@@ -47,6 +55,8 @@ from .huffman import (
 
 WINDOW_SIZE = 32768
 MARKER_BASE = 256  # symbol value 256 + w refers to unknown-window byte w
+#: bytes zlib writes per call before they are copied into a chunk's output
+_NATIVE_PIECE = 1 << 18
 
 BT_STORED = 0
 BT_FIXED = 1
@@ -106,6 +116,7 @@ class DecodeResult:
     ended_at_eos: bool = False  # reached end of the whole file
     first_marker: int = -1  # chunk-local offset of first marker symbol (-1: none)
     last_marker: int = -1  # conservative last position that may hold a marker
+    native_bytes: int = 0  # output bytes zlib produced (block bodies handed off)
 
     @property
     def size(self) -> int:
@@ -123,6 +134,7 @@ class DeflateChunkDecoder:
             raise ValueError("framing must be 'gzip' or 'raw'")
         self.data = data if isinstance(data, (bytes, memoryview)) else bytes(data)
         self.framing = framing
+        self._array: Optional[np.ndarray] = None  # ``data`` as uint8, for zlib
 
     # -- public API ---------------------------------------------------------
 
@@ -139,6 +151,7 @@ class DeflateChunkDecoder:
 
         window=None  -> two-stage marker mode (unknown window).
         window=bytes -> single-stage mode; b"" means known-empty (stream start).
+        ``max_out`` bounds the output: more raises ``DeflateError``.
         """
         total_bits = len(self.data) * 8
         if stop_bit is None:
@@ -147,7 +160,10 @@ class DeflateChunkDecoder:
 
         marker_mode = window is None
         dtype = np.uint16 if marker_mode else np.uint8
-        out = np.empty(max(initial_capacity, 1024), dtype=dtype)
+        capacity = max(initial_capacity, 1024)
+        if max_out is not None:
+            capacity = min(capacity, max_out)  # _DecodeState keeps it so
+        out = np.empty(capacity, dtype=dtype)
         if window:
             win_arr = np.frombuffer(window, dtype=np.uint8)
         else:
@@ -156,7 +172,26 @@ class DeflateChunkDecoder:
 
         state = _DecodeState(out, marker_mode, win_arr, win_len, max_out)
         result = DecodeResult(start_bit=start_bit, end_bit=start_bit, data=out, marker_mode=marker_mode)
+        try:
+            self._decode_blocks(br, state, result, stop_bit)
+        finally:
+            if state.inflater is not None:
+                state.inflater.close()
 
+        result.data = state.out[: state.n]
+        result.first_marker = state.first_marker
+        result.last_marker = state.last_marker
+        result.native_bytes = state.native_bytes
+        if not result.blocks:
+            raise DeflateError("no blocks decoded")
+        return result
+
+    def _decode_blocks(self, br: BitReader, state: "_DecodeState", result: DecodeResult,
+                       stop_bit: int) -> None:
+        """The block loop: decode blocks into ``state`` until the stop
+        condition or the stream's end, recording boundaries, member ends
+        and starts and the end offset in ``result``."""
+        lib = zlib_bridge.libz()
         while True:
             block_start = br.bit_pos
             # +7: a stored block's canonical offset can sit up to 7 bits
@@ -179,7 +214,7 @@ class DeflateChunkDecoder:
                     )
                     if effective >= stop_bit:
                         result.end_bit = effective
-                        break
+                        return
             if br.bits_left() < 3:
                 raise EndOfStream("chunk ran out of bits at block boundary")
 
@@ -188,7 +223,18 @@ class DeflateChunkDecoder:
             result.blocks.append(
                 BlockBoundary(block_start, state.n, btype, bool(is_final))
             )
-            if btype == BT_STORED:
+            if state.inflater is None and lib is not None and state.window_known():
+                # The window for the rest of the member is known: zlib
+                # takes the block bodies from here (paper §3.3).
+                state.inflater = zlib_bridge.BlockInflater(
+                    lib, self._as_array(), block_start, state.window_bytes()
+                )
+            if state.inflater is not None:
+                # zlib reads the block from its header; this loop resumes
+                # at the block's end.
+                self._inflate_block(state)
+                br.seek(state.inflater.bit_pos)
+            elif btype == BT_STORED:
                 self._decode_stored(br, state)
             elif btype == BT_FIXED:
                 self._decode_huffman(br, state, FIXED_LITERAL_LUT, FIXED_DISTANCE_LUT)
@@ -199,10 +245,14 @@ class DeflateChunkDecoder:
                 raise DeflateError("reserved block type 11")
 
             if is_final:
+                if state.inflater is not None:
+                    # A raw inflate stream ends with its final block.
+                    state.inflater.close()
+                    state.inflater = None
                 if self.framing == "raw":
                     result.end_bit = br.bit_pos
                     result.ended_at_eos = True
-                    break
+                    return
                 # gzip footer: byte-align, CRC32 + ISIZE (paper Fig 1).
                 br.align_to_byte()
                 footer = parse_gzip_footer(br)
@@ -212,21 +262,19 @@ class DeflateChunkDecoder:
                 if br.bits_left() < 8:
                     result.end_bit = br.bit_pos
                     result.ended_at_eos = True
-                    break
+                    return
                 header_start = br.bit_pos
-                hdr = parse_gzip_header(br)
+                parse_gzip_header(br)
                 result.member_starts.append(
                     MemberStart(header_start, br.bit_pos, state.n)
                 )
                 # Next member's first block continues the loop; the stop
                 # check at the top applies to it like any other boundary.
 
-        result.data = state.out[: state.n]
-        result.first_marker = state.first_marker
-        result.last_marker = state.last_marker
-        if not result.blocks:
-            raise DeflateError("no blocks decoded")
-        return result
+    def _as_array(self) -> np.ndarray:
+        if self._array is None:
+            self._array = np.frombuffer(self.data, dtype=np.uint8)
+        return self._array
 
     # -- block bodies ---------------------------------------------------------
 
@@ -237,7 +285,19 @@ class DeflateChunkDecoder:
         if length != (~nlen & 0xFFFF):
             raise DeflateError("stored block LEN/NLEN mismatch")
         raw = br.read_bytes(length)
-        state.append_literal_bytes(raw)
+        state.append_bytes(np.frombuffer(raw, dtype=np.uint8))
+
+    def _inflate_block(self, state: "_DecodeState") -> None:
+        """The current block's body, by ``state.inflater`` (zlib), through a
+        byte buffer into ``state``'s output."""
+        if state.scratch is None:
+            state.scratch = np.empty(_NATIVE_PIECE, dtype=np.uint8)
+        while True:
+            n, ended = state.inflater.inflate_block(state.scratch)
+            state.append_bytes(state.scratch[:n])
+            state.native_bytes += n
+            if ended:
+                return
 
     def _decode_huffman(
         self,
@@ -359,10 +419,13 @@ class _DecodeState:
         "max_out",
         "first_marker",
         "last_marker",
+        "inflater",
+        "scratch",
+        "native_bytes",
     )
 
     def __init__(self, out, marker_mode, win_arr, win_len, max_out):
-        self.out = out
+        self.out = out  # capacity stays <= max_out, so growth sees every overflow
         self.n = 0
         self.marker_mode = marker_mode
         self.win_arr = win_arr
@@ -370,6 +433,26 @@ class _DecodeState:
         self.max_out = max_out
         self.first_marker = -1
         self.last_marker = -1
+        self.inflater = None  # zlib_bridge.BlockInflater while zlib decodes
+        self.scratch = None  # its byte buffer
+        self.native_bytes = 0
+
+    # -- window -------------------------------------------------------------
+
+    def window_known(self) -> bool:
+        """Are the 32 KiB before the next byte fully known? Always in window
+        mode; in marker mode once they hold no marker (with no marker at
+        all, once the chunk's own output reaches 32 KiB). Once true it stays
+        true: no distance reaches further back."""
+        return not self.marker_mode or self.n - 1 - self.last_marker >= WINDOW_SIZE
+
+    def window_bytes(self) -> bytes:
+        """The (at most) 32 KiB before the next byte, as bytes."""
+        n = self.n
+        if n >= WINDOW_SIZE:
+            return self.out[n - WINDOW_SIZE : n].astype(np.uint8).tobytes()
+        head = self.win_arr[max(0, self.win_len - (WINDOW_SIZE - n)) :]
+        return head.tobytes() + self.out[:n].tobytes()
 
     # -- capacity -----------------------------------------------------------
 
@@ -383,9 +466,11 @@ class _DecodeState:
                 "chunk output exceeds max_out=%d (suspected false positive or "
                 "extreme compression ratio)" % self.max_out
             )
-        new_cap = cap
+        new_cap = max(cap, 1)
         while new_cap < need:
             new_cap *= 2
+        if self.max_out is not None:
+            new_cap = min(new_cap, self.max_out)
         grown = np.empty(new_cap, dtype=self.out.dtype)
         grown[: self.n] = self.out[: self.n]
         self.out = grown
@@ -397,16 +482,14 @@ class _DecodeState:
         self.out[self.n] = value
         self.n += 1
 
-    def append_literal_bytes(self, raw: bytes) -> None:
-        if not raw:
+    def append_bytes(self, arr: np.ndarray) -> None:
+        """Append resolved bytes (uint8; widened to uint16 in marker mode)."""
+        k = arr.shape[0]
+        if not k:
             return
-        self._ensure(len(raw))
-        arr = np.frombuffer(raw, dtype=np.uint8)
-        if self.marker_mode:
-            self.out[self.n : self.n + len(raw)] = arr  # widens to uint16
-        else:
-            self.out[self.n : self.n + len(raw)] = arr
-        self.n += len(raw)
+        self._ensure(k)
+        self.out[self.n : self.n + k] = arr
+        self.n += k
 
     def copy_match(self, dist: int, length: int) -> None:
         if dist > WINDOW_SIZE:
@@ -472,7 +555,8 @@ def gzip_decompress_sequential(data: bytes, *, verify: bool = True) -> bytes:
     """Sequentially decompress a (multi-member) gzip byte stream.
 
     This is the paper's single-threaded baseline path ("rapidgzip -P 1"): the
-    same custom deflate decoder, no speculation, known-empty window.
+    same chunk decoder, no speculation, known-empty window, so zlib decodes
+    every block body where it can be loaded.
     """
     import zlib as _zlib
 

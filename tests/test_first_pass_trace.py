@@ -176,14 +176,14 @@ def test_nominal_trial_into_a_bad_header_is_a_false_start(corpus):
 
 # -- metric readers ------------------------------------------------------------
 
-def task(kind, dur, cpu, nbytes):
+def task(kind, dur, cpu, nbytes, **attrs):
     return {"name": "fetcher.task", "dur_s": dur,
-            "attrs": {"kind": kind, "key": "0", "cpu_s": cpu, "bytes": nbytes}}
+            "attrs": {"kind": kind, "key": "0", "cpu_s": cpu, "bytes": nbytes, **attrs}}
 
 
 SYNTHETIC = [
-    task("nom", 2.0, 0.5, 3_000_000),
-    task("fp", 1.0, 0.5, 1_000_000),
+    task("nom", 2.0, 0.5, 3_000_000, native_bytes=2_500_000),
+    task("fp", 1.0, 0.5, 1_000_000, native_bytes=500_000),
     task("ix", 5.0, 5.0, 9_000_000),  # indexed reads are not stage 1
     {"name": "reader.frontier_wait", "dur_s": 4.0, "attrs": {}},
     {"name": "fetcher.chunk_wait", "dur_s": 3.0, "attrs": {"source": "nominal"}},
@@ -198,6 +198,7 @@ SYNTHETIC = [
     ("stage1_useful_share.scan", 75.0),             # 3 of 4 MB finalized
     ("frontier_stage1_wait_share.scan", 30.0),      # 3 s of a 10 s window
     ("frontier_stage2_wait_share.scan", 7.5),       # 0.75 s of 10 s
+    ("stage1_native_share.scan", 75.0),             # zlib decoded 3 of 4 MB
 ])
 def test_first_pass_metric_readers(metric, expected):
     from types import SimpleNamespace
@@ -205,6 +206,43 @@ def test_first_pass_metric_readers(metric, expected):
     run = SimpleNamespace(window_s=10.0, spans=SYNTHETIC, fetcher={"bytes_decompressed": 3_000_000},
                           engine={}, trace=None, device_kind="TPU v5 lite")
     assert Registry().metric(metric)(run) == pytest.approx(expected)
+
+
+def test_native_share_reads_a_recorded_scan(monkeypatch):
+    """Over a traced cold scan the share reads the spans' ``native_bytes``
+    (zlib takes over 32 KiB into each chunk of small-delta integers); over
+    the same scan without zlib it reads 0."""
+    from types import SimpleNamespace
+
+    from repro.core import zlib_bridge
+
+    rng = np.random.default_rng(0x1B)
+    data = np.cumsum(rng.integers(0, 16, 80_000)).astype("<u4").tobytes()
+    comp = gzip_bytes(data, 6)
+    read = Registry().metric("stage1_native_share.scan")
+    shares = []
+    for libz in (zlib_bridge.libz, lambda: None):
+        monkeypatch.setattr(zlib_bridge, "libz", libz)
+        obs_trace.reset_tracing()
+        obs_trace.enable_tracing(1 << 16)
+        pool = ThreadPoolExecutor(max_workers=2)
+        try:
+            with ParallelGzipReader(comp, parallelization=2, chunk_size=48 << 10,
+                                    executor=pool) as r:
+                assert r.pread(0, 1 << 30) == data
+        finally:
+            pool.shutdown(wait=True)
+            obs_trace.disable_tracing()
+        spans = obs_trace.recorded_spans()
+        tasks = [s["attrs"] for s in by_name(spans, "fetcher.task")
+                 if s["attrs"]["kind"] in ("nom", "fp")]
+        native = sum(a.get("native_bytes", 0) for a in tasks)
+        decoded = sum(a["bytes"] for a in tasks)
+        run = SimpleNamespace(window_s=1.0, spans=spans, fetcher={}, engine={}, trace=None,
+                              device_kind="TPU v5 lite")
+        assert read(run) == pytest.approx(100.0 * native / decoded)
+        shares.append(read(run))
+    assert shares[0] > 50.0 and shares[1] == 0.0
 
 
 # -- one clock with the device trace ------------------------------------------

@@ -105,6 +105,40 @@ def test_pool_results_equal_in_thread_results(archive, pool, request):
     assert stats == offloaded
 
 
+@pytest.fixture(scope="module")
+def ints_gz():
+    """Small-delta integers: markers vanish about 32 KiB into a chunk, so
+    zlib decodes the rest of each 48 KiB (compressed) chunk."""
+    rng = np.random.default_rng(0x1A)
+    data = np.cumsum(rng.integers(0, 16, 100_000)).astype("<u4").tobytes()
+    return data, gzip_bytes(data, 6)
+
+
+def test_native_bytes_reach_the_fetcher_from_a_worker(ints_gz, pool):
+    data, comp = ints_gz
+    here = fetcher(comp, chunk_size=48 << 10)
+    there = fetcher(comp, pool, chunk_size=48 << 10)
+    mine, theirs = nominal_pass(here), nominal_pass(there)
+    found = [r for r in theirs if r is not None]
+    assert len(found) >= 2 and all(r.native_bytes > 0 for r in found[1:])
+    for a, b in zip(mine, theirs):
+        assert (a is None) == (b is None)
+        if a is not None:
+            assert_same_result(a, b)
+    assert there.stats.stage1_native_bytes == sum(r.native_bytes for r in found)
+    assert here.stats.stage1_native_bytes == there.stats.stage1_native_bytes
+    # An exact task given the window hands every block to zlib.
+    first = found[0]
+    assert first.start_bit == 80 and found[1].start_bit == first.end_bit
+    exact = fetcher(comp, pool, chunk_size=48 << 10)
+    try:
+        res = exact._task_exact(first.end_bit, data[first.size - (32 << 10) : first.size])
+    finally:
+        exact.shutdown()
+    assert res.native_bytes == res.size == found[1].size
+    assert exact.stats.stage1_native_bytes == res.native_bytes
+
+
 def test_false_starts_in_a_worker_count_as_in_thread(damaged_raw, pool):
     here = fetcher(damaged_raw, framing="raw")
     there = fetcher(damaged_raw, pool, framing="raw")
@@ -145,12 +179,12 @@ def test_only_codecs_a_worker_can_rebuild_are_offloaded(pool):
 
 # -- the server's pool --------------------------------------------------------
 
-def served_scan(path: str, data: bytes, **kwargs):
+def served_scan(path: str, data: bytes, chunk_size: int = 16 << 10, **kwargs):
     """A cold scan of ``path`` through a fresh `ArchiveServer`; returns the
     bytes, the fleet's fetcher counters and the server, shut down."""
     from repro.service import ArchiveServer
 
-    server = ArchiveServer(max_workers=2, chunk_size=16 << 10, device_engine="off",
+    server = ArchiveServer(max_workers=2, chunk_size=chunk_size, device_engine="off",
                            transcode="off", **kwargs)
     try:
         handle = server.open(path)
@@ -216,6 +250,27 @@ def test_traced_offloaded_task_counts_the_workers_cpu(tmp_path, two_cpus):
     assert all(a["offloaded"] is False for a in tasks if a["kind"] == "ix")
     decoded = [s for s in spans if s["name"] == "fetcher.task" and s["attrs"]["bytes"]]
     assert decoded and all(0 < s["attrs"]["cpu_s"] <= s["dur_s"] + 1e-3 for s in decoded)
+
+
+def test_traced_offloaded_task_carries_native_bytes(tmp_path, two_cpus, ints_gz):
+    data, comp = ints_gz
+    path = tmp_path / "ints.gz"
+    path.write_bytes(comp)
+    obs_trace.enable_tracing(1 << 16)
+    obs_trace.reset_tracing()
+    try:
+        got, fetched, _ = served_scan(str(path), data, chunk_size=48 << 10)
+        spans = obs_trace.recorded_spans()
+    finally:
+        obs_trace.disable_tracing()
+        obs_trace.reset_tracing()
+    assert got == data
+    stage1 = [s["attrs"] for s in spans
+              if s["name"] == "fetcher.task" and s["attrs"]["kind"] in ("nom", "fp")]
+    assert stage1 and all(a["offloaded"] is True for a in stage1)
+    found = [a for a in stage1 if a["bytes"]]
+    assert all(0 <= a["native_bytes"] <= a["bytes"] for a in found)
+    assert sum(a["native_bytes"] for a in found) == fetched["stage1_native_bytes"] > 0
 
 
 def test_shutdown_leaves_no_worker_alive(two_cpus):
