@@ -27,6 +27,8 @@ raises before it is printed.
 
 from __future__ import annotations
 
+import gzip
+import importlib.util
 import json
 import os
 import sys
@@ -35,7 +37,7 @@ import time
 import zlib
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+sys.path.insert(0, os.path.join(ROOT, "src"))
 
 #: Deployment sizes. The gzip archive is cut from the paper's multi-GB
 #: Silesia-scale inputs to 32 MiB: its stage 1 is host-side pure Python.
@@ -53,6 +55,17 @@ def log(msg: str) -> None:
 def check(ok: bool, what: str) -> None:
     if not ok:
         raise AssertionError(what)
+
+
+def use_compile_cache() -> str:
+    """JAX's persistent compile cache with every compile kept: where
+    ``JAX_COMPILATION_CACHE_DIR`` names it, else ``<repo>/.jax_cache``."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(ROOT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir
 
 
 class CompileClock:
@@ -176,17 +189,24 @@ def kernel_parity(rng, *, tiles=32, tables=8, crc_batch=16, crc_words=1024,
 
 # -- phase 3 -------------------------------------------------------------------
 
+def _generator(kind: str):
+    """``generate(rng, n)`` of the chip benchmark's ``data/<kind>.py``."""
+    path = os.path.join(ROOT, "benchmarks", "chip", "data", kind + ".py")
+    spec = importlib.util.spec_from_file_location("chip_smoke_" + kind, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.generate
+
+
 def make_archives(workdir: str, rng, *, gzip_bytes: int, bgzf_bytes: int):
     """Seeded sources and their archives on disk: [(label, path, source)]."""
-    from benchmarks.common import DataGen, gzip_bytes as gzip6
     from repro.core.synth import bgzf_compress
 
-    gen = DataGen(int(rng.integers(1 << 31)))
-    text = gen.silesia_like(gzip_bytes)
-    fastq = gen.fastq_like(bgzf_bytes)
+    text = _generator("silesia_like")(rng, gzip_bytes)
+    fastq = _generator("fastq_like")(rng, bgzf_bytes)
     out = []
     for label, source, archive in (
-        ("gzip-6 silesia-like", text, gzip6(text, 6)),
+        ("gzip-6 silesia-like", text, gzip.compress(text, compresslevel=6)),
         ("bgzf fastq-like", fastq, bgzf_compress(fastq, 6)),
     ):
         path = os.path.join(workdir, label.split()[0] + ".gz")
@@ -287,8 +307,6 @@ def main() -> None:
     devices = device_check()
 
     import numpy as np
-
-    from benchmarks.common import use_compile_cache
 
     log("compile cache: %s" % use_compile_cache())
     clock = CompileClock()

@@ -75,8 +75,9 @@ A ``Codec`` must provide:
     Stage-2 marker machinery; windowless codecs inherit the no-op defaults.
 ``set_stage2_resolver(resolver)``
     Optional pluggable stage-2 back end (``kernels.engine``): when set,
-    marker resolution routes through it (batched device dispatch with CPU
-    crossover); output stays bit-identical either way.
+    marker resolution routes through it (batched on the device, or on the
+    CPU where the resolver routes it there); output stays bit-identical
+    either way.
 ``split_candidate(block)``
     For marker codecs: may the on-the-fly indexer place an interior seek
     point at this block boundary? Returns ``(bit_offset, flags)`` or None.

@@ -30,13 +30,11 @@ Layout and policy:
     alternate between dispatches, and result readback of batch N overlaps
     the launch of batch N+1 (one dispatch in flight), so host packing and
     device compute pipeline instead of serializing.
-  * **Crossover routing** — small or singleton requests take the existing
-    CPU path inline (``core.markers`` / ``zlib.crc32``) and are counted as
-    ``fallbacks``: interactive p99 never pays the batching latency tax. The
-    threshold is derived from the committed ``BENCH_kernels.json`` batched
-    dispatch sweep (see ``derive_crossover``); where the device never wins
-    on that artifact the derived crossover is None and *everything* falls
-    back unless ``force_device`` is set.
+  * **Routing** — without ``force_device`` every request is resolved on
+    the CPU inline (``core.markers`` / ``zlib.crc32``) and counted in
+    ``fallbacks``; with it, every request goes to the device. A size
+    threshold measured on the device belongs in ``_route_device``, the one
+    place the rule lives.
   * **One device** — every array the engine dispatches is placed on
     ``self.device`` (the first device JAX reports), and the kernels are
     interpreted only when that device is the CPU.
@@ -49,15 +47,12 @@ bit-identical regardless of routing — verified by the parity suite in
 
 from __future__ import annotations
 
-import json
-import os
-import re
 import threading
 import time
 import zlib as _zlib
 from collections import OrderedDict, deque
 from concurrent.futures import Future
-from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -81,8 +76,6 @@ from .marker_replace import (
 from .ops import interpret_on
 from .ref import make_replacement_table
 
-_TILE_BYTES = TILE  # one symbol resolves to one output byte
-
 
 class EngineClosedError(RuntimeError):
     """Raised on futures queued (or submits attempted) after shutdown."""
@@ -93,84 +86,6 @@ def _pow2_at_least(n: int, cap: Optional[int] = None) -> int:
     while p < n:
         p *= 2
     return min(p, cap) if cap is not None else p
-
-
-_MBPS_RE = re.compile(r"([0-9]+(?:\.[0-9]+)?)MB/s")
-
-
-def derive_crossover(rows: Sequence[Dict[str, Any]]) -> Dict[str, Optional[int]]:
-    """Roofline-style CPU/device crossover from ``BENCH_kernels.json`` rows.
-
-    Model: CPU resolves a request of ``n`` bytes in ``n / cpu_bw`` seconds;
-    the device costs a fixed per-dispatch overhead plus ``n / dev_bw``. The
-    crossover is where the lines meet::
-
-        n* = overhead / (1/cpu_bw - 1/dev_bw)      (only if dev_bw > cpu_bw)
-
-    Inputs are the sweep rows ``bench_kernels`` persists:
-      * ``kernel_engine_cpu_replace``  — CPU gather bandwidth (MB/s derived)
-      * ``kernel_engine_batched_b16``  — batched device bandwidth (MB/s)
-      * ``kernel_engine_batched_b1``   — single-tile dispatch latency (us),
-        whose fixed part estimates the per-dispatch overhead.
-
-    Returns ``{"replace": bytes_or_None, "crc": bytes_or_None}`` — None
-    means the device never wins at any size on this artifact (the honest
-    answer for interpret mode on a CPU-only host) and all requests of that
-    kind should take the CPU path.
-    """
-    by_name = {r.get("name"): r for r in rows or ()}
-
-    def _mbps(name: str) -> Optional[float]:
-        row = by_name.get(name)
-        if not row:
-            return None
-        m = _MBPS_RE.search(str(row.get("derived", "")))
-        return float(m.group(1)) * 1e6 if m else None
-
-    def _us(name: str) -> Optional[float]:
-        row = by_name.get(name)
-        return float(row["value_us"]) if row and "value_us" in row else None
-
-    def _one(cpu_name: str, dev_name: str, b1_name: str) -> Optional[int]:
-        cpu_bw, dev_bw, b1 = _mbps(cpu_name), _mbps(dev_name), _us(b1_name)
-        if not cpu_bw or not dev_bw or b1 is None or dev_bw <= cpu_bw:
-            return None
-        overhead_s = max(0.0, b1 * 1e-6 - _TILE_BYTES / dev_bw)
-        if overhead_s == 0.0:
-            return _TILE_BYTES
-        return int(overhead_s / (1.0 / cpu_bw - 1.0 / dev_bw))
-
-    return {
-        "replace": _one(
-            "kernel_engine_cpu_replace",
-            "kernel_engine_batched_b16",
-            "kernel_engine_batched_b1",
-        ),
-        "crc": _one(
-            "kernel_engine_cpu_crc",
-            "kernel_engine_crc_batched_b8",
-            "kernel_engine_crc_batched_b1",
-        ),
-    }
-
-
-def load_crossover(root: Optional[str] = None) -> Dict[str, Optional[int]]:
-    """``derive_crossover`` over the committed ``BENCH_kernels.json``.
-
-    Missing or malformed artifacts degrade to all-None (CPU path) — an
-    installed package without the repo checkout must still construct.
-    """
-    if root is None:
-        root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        )
-    path = os.path.join(root, "BENCH_kernels.json")
-    try:
-        with open(path) as f:
-            payload = json.load(f)
-        return derive_crossover(payload.get("results", []))
-    except (OSError, ValueError):
-        return {"replace": None, "crc": None}
 
 
 class _Request:
@@ -217,9 +132,7 @@ class DeviceDecodeEngine:
         max_batch_crc_bytes: int = 4 << 20,
         max_crc_requests: int = 16,
         max_delay_s: float = 0.002,
-        crossover: Union[str, None, Dict[str, Optional[int]]] = "auto",
         force_device: bool = False,
-        artifact_root: Optional[str] = None,
     ):
         self.max_batch_tiles = max(1, max_batch_tiles)
         self.max_tables = _pow2_at_least(max(1, max_tables))
@@ -231,15 +144,6 @@ class DeviceDecodeEngine:
         self.force_device = force_device
         self.device = jax.devices()[0]
         self.interpret = interpret_on(self.device)
-        if crossover == "auto":
-            self.crossover = load_crossover(artifact_root)
-        elif crossover is None:
-            self.crossover = {"replace": None, "crc": None}
-        else:
-            self.crossover = {
-                "replace": crossover.get("replace"),
-                "crc": crossover.get("crc"),
-            }
 
         self._cond = threading.Condition()
         self._rq: Deque[_Request] = deque()
@@ -282,13 +186,8 @@ class DeviceDecodeEngine:
     # routing policy
     # ------------------------------------------------------------------
 
-    def _route_device(self, kind: str, nbytes: int) -> bool:
-        if self._closed:
-            return False
-        if self.force_device:
-            return True
-        threshold = self.crossover.get(kind)
-        return threshold is not None and nbytes >= threshold
+    def _route_device(self) -> bool:
+        return not self._closed and self.force_device
 
     def _count(self, counter: Dict[str, int], kind: str) -> None:
         with self._cond:
@@ -349,11 +248,11 @@ class DeviceDecodeEngine:
     # ------------------------------------------------------------------
 
     def replace_markers(self, symbols: np.ndarray, window: Optional[bytes]) -> np.ndarray:
-        """Resolve a marker stream — batched on-device above the crossover,
-        inline on the CPU below it (or whenever the device cannot win)."""
+        """Resolve a marker stream — batched on the device where
+        ``_route_device`` says so, inline on the CPU otherwise."""
         if symbols.dtype == np.uint8:
             return symbols
-        if self._route_device("replace", symbols.shape[0]):
+        if self._route_device():
             try:
                 fut = self.submit_replace(symbols, window)
                 with _obs_trace.timed(
@@ -368,16 +267,16 @@ class DeviceDecodeEngine:
         return _cpu_replace_markers(symbols, window)
 
     def crc32(self, data) -> int:
-        """CRC32 — batched on-device above the crossover, zlib below it."""
+        """CRC32 — batched on the device where ``_route_device`` says so,
+        zlib otherwise."""
         return self.crc32_many([data])[0][0]
 
     def crc32_many(self, datas: Sequence) -> Tuple[List[int], bool]:
-        """CRC32 of each of ``datas`` as one request, routed by their total
-        size like ``crc32``; returns the checksums and whether the device
-        computed them."""
+        """CRC32 of each of ``datas`` as one request, routed like ``crc32``;
+        returns the checksums and whether the device computed them."""
         datas = [_as_bytes(d) for d in datas]
         nbytes = sum(len(d) for d in datas)
-        if self._route_device("crc", nbytes):
+        if self._route_device():
             try:
                 fut = self.submit_crcs(datas)
                 with _obs_trace.timed("engine.batch_wait", {"kind": "crc", "nbytes": nbytes}):
@@ -731,7 +630,6 @@ class DeviceDecodeEngine:
                 "platform": self.device.platform,
                 "interpret": self.interpret,
                 "force_device": self.force_device,
-                "crossover_bytes": dict(self.crossover),
                 "requests": dict(self._requests),
                 "fallbacks": dict(self._fallbacks),
                 "batches": self._batches,

@@ -12,8 +12,7 @@ Per cell this lowers the *real* step function (train_step with AdamW+ZeRO-1
 for train shapes; prefill/serve steps for inference shapes), compiles it,
 prints ``memory_analysis()`` (proof-of-fit) and ``cost_analysis()``, parses
 the collective mix out of the optimized HLO, and appends everything to a
-resumable JSON results file consumed by EXPERIMENTS.md §Dry-run/§Roofline
-and benchmarks/roofline_report.py.
+resumable JSON results file.
 
 Usage:
   python -m repro.launch.dryrun --arch all --shape all --mesh both \

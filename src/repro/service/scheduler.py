@@ -40,8 +40,8 @@ still waits. A client abandoning a request can therefore never orphan a
 task: it either runs, or it is accounted cancelled.
 
 ``fairness="task_rr"`` restores the legacy task-count round-robin (costs and
-lanes ignored) so the two disciplines can be A/B-measured — see
-benchmarks/bench_service.py's skewed-tenant scenario.
+lanes ignored) so the two disciplines can be A/B-compared; only tests
+select it now (ROADMAP C.2).
 
 Readers receive a `TenantExecutor` view: submit-compatible with
 ThreadPoolExecutor (the fetcher calls only ``submit``/``shutdown``), tagging

@@ -33,7 +33,7 @@ Concurrency contract (who locks what):
     aggregate throughput scales with the executor, not with handle count.
     ``read_range(..., serialized=True)`` keeps the legacy one-cursor-
     per-handle discipline (entry lock around seek+read) for A/B
-    measurement — see bench_service's concurrent-scaling scenario.
+    comparison; only tests select it now (ROADMAP C.2).
   * the **entry lock** is a lifecycle lock only: lazy open (exactly one
     thread builds the reader) and close (nobody closes a reader out from
     under an opener). Reads never hold it.

@@ -9,12 +9,11 @@ GET/HEAD, ETag + Last-Modified validators, and ``If-Range`` semantics
   * ``inject_short(n)``   — next n range bodies are cut in half mid-wire
                             (Content-Length promises more; connection drops)
   * ``drop_ranges``       — ignore Range headers entirely (200 full body)
-  * ``latency``           — per-request sleep, for benchmark latency models
+  * ``latency``           — per-request sleep, modelling a far origin
   * ``flip_etag()``       — swap payload/ETag at runtime (object replaced)
 
-Used by the FileReader contract suite, the remote-backend tests, and
-``benchmarks/bench_service.bench_remote``. Loopback only — no external
-network — so tier-1 stays offline-safe.
+Used by the FileReader contract suite and the remote-backend tests.
+Loopback only — no external network — so tier-1 stays offline-safe.
 """
 
 from __future__ import annotations

@@ -89,8 +89,7 @@ def pytest_configure(config):
     # engine) run the kernel bodies in Python — correct but slow. The marker
     # gives them a selection handle: `-m kernels` for the kernel lane,
     # `-m "not kernels"` for a fast CPU-only pass. They still run in plain
-    # tier-1; the tier-2 perf gate is
-    # `python -m benchmarks.run --smoke --only kernels,codecs` (ROADMAP).
+    # tier-1; speed is measured on the chip (BENCHMARK.json), not here.
     config.addinivalue_line(
         "markers",
         "kernels: Pallas interpret-mode kernel/engine tests (slow kernel-"

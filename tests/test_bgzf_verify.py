@@ -58,7 +58,7 @@ def corpus():
 
 @pytest.fixture
 def engine():
-    eng = DeviceDecodeEngine(force_device=True, crossover=None, max_delay_s=0.002)
+    eng = DeviceDecodeEngine(force_device=True, max_delay_s=0.002)
     yield eng
     eng.shutdown()
 
@@ -157,7 +157,7 @@ def test_members_per_task_follow_the_resolver_batch(corpus, batch_bytes, paralle
     together; without a resolver a task is one member, the unit of random
     access."""
     data, archive = corpus
-    eng = (DeviceDecodeEngine(force_device=True, crossover=None, max_batch_crc_bytes=batch_bytes)
+    eng = (DeviceDecodeEngine(force_device=True, max_batch_crc_bytes=batch_bytes)
            if batch_bytes else None)
     try:
         with ParallelGzipReader(archive, resolver=eng, parallelization=parallelization) as r:
@@ -198,7 +198,7 @@ def test_every_inflate_is_counted_and_crcd(corpus):
     """A task evicted and read again is inflated and CRC'd again, and counted
     again, so ``bytes_decompressed`` equals the engine's ``crc_bytes``."""
     data, archive = corpus
-    eng = DeviceDecodeEngine(force_device=True, crossover=None, max_batch_crc_bytes=1 << 18)
+    eng = DeviceDecodeEngine(force_device=True, max_batch_crc_bytes=1 << 18)
     try:
         with ParallelGzipReader(archive, resolver=eng, parallelization=1) as r:
             assert r._fetcher.task_points == 4  # 6 tasks, caches of 1 and 2
@@ -267,7 +267,7 @@ def test_a_damaged_twin_is_never_served(corpus):
     store = IndexStore()
     store.register_twin(file_identity(origin), codec_tag="bgzf", data=twin, index=index)
     server = ArchiveServer(index_store=store, transcode="off",
-                           engine_options={"force_device": True, "crossover": None})
+                           engine_options={"force_device": True})
     try:
         h = server.open(origin)
         assert server.read_range(h, 0, 1000) == data[:1000]
@@ -297,7 +297,7 @@ def test_a_full_batch_goes_without_waiting():
     room for another request like its largest goes at once."""
     rng = np.random.default_rng(12)
     tasks = [[rng.bytes(BLOCK) for _ in range(16)] for _ in range(3)]
-    slow = DeviceDecodeEngine(force_device=True, crossover=None, max_delay_s=0.5,
+    slow = DeviceDecodeEngine(force_device=True, max_delay_s=0.5,
                               max_crc_requests=2)
     try:
         futs = [slow.submit_crcs(t) for t in tasks]
@@ -306,7 +306,7 @@ def test_a_full_batch_goes_without_waiting():
         assert slow.stats()["dispatches"] == 2  # two requests, then one
     finally:
         slow.shutdown()
-    small = DeviceDecodeEngine(force_device=True, crossover=None, max_delay_s=30.0,
+    small = DeviceDecodeEngine(force_device=True, max_delay_s=30.0,
                                max_batch_crc_bytes=len(tasks[0]) * BLOCK * 3 // 2)
     try:
         small.crc32_many(tasks[0])  # compiles outside the timing below
@@ -323,7 +323,7 @@ def test_requests_share_one_dispatch(engine):
     rng = np.random.default_rng(11)
     many = [rng.bytes(n) for n in (BLOCK, 17, 5000)]
     one = rng.bytes(12_345)
-    slow = DeviceDecodeEngine(force_device=True, crossover=None, max_delay_s=0.2)
+    slow = DeviceDecodeEngine(force_device=True, max_delay_s=0.2)
     try:
         fut_many, fut_one = slow.submit_crcs(many), slow.submit_crc(one)
         assert fut_many.result(timeout=60) == [zlib.crc32(d) for d in many]

@@ -5,8 +5,8 @@ Covers the engine's whole contract surface:
     including ragged last tiles, empty chunks, and >1-slab requests;
   * coalescing of interleaved multi-tenant submissions into shared batches;
   * CRC parity (device lanes + GF(2) combine, zero-padded first lanes) vs zlib;
-  * crossover routing (small/singleton requests take the CPU path and are
-    counted as fallbacks) and the derive_crossover math itself;
+  * routing (without ``force_device`` every request takes the CPU path and
+    is counted as a fallback);
   * shutdown-while-queued and readback failures — futures error, never hang;
   * the threading through codec -> fetcher -> reader -> server, with
     engine stats exported from ``ArchiveServer.metrics()``.
@@ -21,11 +21,7 @@ import numpy as np
 import pytest
 
 from repro.core.markers import replace_markers as cpu_replace
-from repro.kernels.engine import (
-    DeviceDecodeEngine,
-    EngineClosedError,
-    derive_crossover,
-)
+from repro.kernels.engine import DeviceDecodeEngine, EngineClosedError
 
 from conftest import make_random, make_text
 
@@ -36,7 +32,6 @@ TABLE_SIZE = 256 + 32768
 
 def make_engine(**kw):
     kw.setdefault("force_device", True)
-    kw.setdefault("crossover", None)
     kw.setdefault("max_delay_s", 0.005)
     return DeviceDecodeEngine(**kw)
 
@@ -171,81 +166,29 @@ def test_crc_batch_of_mixed_sizes(rng):
 
 
 # ---------------------------------------------------------------------------
-# routing / crossover
+# routing
 # ---------------------------------------------------------------------------
 
-def test_singleton_requests_take_cpu_path(rng):
-    """Default engine on an interpret host: the derived crossover never lets
-    the device win, so interactive singletons go to the CPU inline and the
-    stats record them as fallbacks (never queued, never batched)."""
-    eng = DeviceDecodeEngine()  # crossover="auto"
-    try:
-        syms = make_syms(rng, 5000)
-        window = make_window(rng)
-        np.testing.assert_array_equal(
-            eng.replace_markers(syms, window), cpu_replace(syms, window)
-        )
-        blob = make_random(rng, 10_000)
-        assert eng.crc32(blob) == (zlib.crc32(blob) & 0xFFFFFFFF)
+@pytest.mark.parametrize("n", [100, 8192])
+@pytest.mark.parametrize("kind", ["replace", "crc"])
+def test_singleton_requests_take_cpu_path(rng, kind, n):
+    """A default engine (no ``force_device``) resolves every request on the
+    CPU inline, whatever its size, and records it as a fallback (never
+    queued, never batched)."""
+    with DeviceDecodeEngine() as eng:
+        if kind == "replace":
+            syms = make_syms(rng, n)
+            window = make_window(rng)
+            np.testing.assert_array_equal(
+                eng.replace_markers(syms, window), cpu_replace(syms, window)
+            )
+        else:
+            blob = make_random(rng, n)
+            assert eng.crc32(blob) == (zlib.crc32(blob) & 0xFFFFFFFF)
         stats = eng.stats()
-        assert stats["fallbacks"]["replace"] >= 1
-        assert stats["fallbacks"]["crc"] >= 1
+        assert stats["requests"][kind] == 1
+        assert stats["fallbacks"][kind] == 1
         assert stats["batches"] == 0
-    finally:
-        eng.shutdown()
-
-
-def test_explicit_crossover_routes_by_size(rng):
-    """With an explicit byte threshold, only requests at/above it reach the
-    device queue; smaller ones fall back."""
-    eng = DeviceDecodeEngine(
-        crossover={"replace": 4096, "crc": None}, max_delay_s=0.005
-    )
-    try:
-        small = make_syms(rng, 100)
-        big = make_syms(rng, 8192)
-        window = make_window(rng)
-        np.testing.assert_array_equal(
-            eng.replace_markers(small, window), cpu_replace(small, window)
-        )
-        np.testing.assert_array_equal(
-            eng.replace_markers(big, window), cpu_replace(big, window)
-        )
-        stats = eng.stats()
-        assert stats["fallbacks"]["replace"] == 1
-        assert stats["batches"] == 1
-    finally:
-        eng.shutdown()
-
-
-def test_derive_crossover_math():
-    rows = [
-        {"name": "kernel_engine_cpu_replace", "value_us": 50.0,
-         "derived": "100MB/s"},
-        {"name": "kernel_engine_batched_b16", "value_us": 100.0,
-         "derived": "400MB/s"},
-        {"name": "kernel_engine_batched_b1", "value_us": 120.0,
-         "derived": "70MB/s"},
-    ]
-    out = derive_crossover(rows)
-    # overhead = 120us - 8192B/400MBps (~20us) ~ 100us;
-    # crossover = overhead / (1/100MBps - 1/400MBps) ~ 13.4 KB
-    assert out["replace"] is not None
-    assert 8_000 < out["replace"] < 20_000
-    assert out["crc"] is None  # no crc rows given
-
-
-def test_derive_crossover_device_never_wins():
-    rows = [
-        {"name": "kernel_engine_cpu_replace", "value_us": 10.0,
-         "derived": "500MB/s"},
-        {"name": "kernel_engine_batched_b16", "value_us": 5000.0,
-         "derived": "30MB/s"},
-        {"name": "kernel_engine_batched_b1", "value_us": 700.0,
-         "derived": "11MB/s"},
-    ]
-    assert derive_crossover(rows)["replace"] is None
-    assert derive_crossover([])["replace"] is None
 
 
 # ---------------------------------------------------------------------------
