@@ -35,6 +35,7 @@ same canonicalization so cache keys match.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Iterator, List, Optional
 
@@ -51,6 +52,7 @@ _HCLEN_AT = 13  # 4 bits
 _PRECODE_AT = 17  # (HCLEN+4) x 3 bits
 _MAX_PRECODE_BITS = 19 * 3
 _HEADER_PROBE_BITS = _PRECODE_AT + _MAX_PRECODE_BITS  # 74
+_DYNAMIC_BATCH_BITS = 1 << 19
 
 
 # ---------------------------------------------------------------------------
@@ -133,7 +135,7 @@ def scan_dynamic_candidates(
     start_bit: int,
     end_bit: int,
     *,
-    batch_bits: int = 1 << 19,
+    batch_bits: int = _DYNAMIC_BATCH_BITS,
     stats: Optional[FilterStats] = None,
     full_validation: bool = True,
 ) -> Iterator[int]:
@@ -229,10 +231,11 @@ def scan_stored_candidates(
     n_bytes = len(data)
     # p is the byte offset of LEN; candidate bit offset is 8p-3.
     p_min = max(1, (start_bit + 3 + 7) // 8)
-    p_max_total = n_bytes - 4  # LEN+NLEN must fit
+    # LEN+NLEN must fit, and 8p-3 < end_bit.
+    p_max = min(n_bytes - 4, (end_bit + 2) // 8)
     pos = p_min
-    while pos <= p_max_total:
-        hi = min(pos + batch_bytes, p_max_total + 1)
+    while pos <= p_max:
+        hi = min(pos + batch_bytes, p_max + 1)
         buf = np.frombuffer(data, dtype=np.uint8, count=min(hi + 4, n_bytes) - (pos - 1), offset=pos - 1)
         m = hi - pos  # number of candidate byte positions in this batch
         prev = buf[0:m]
@@ -244,10 +247,7 @@ def scan_stored_candidates(
         nlen = nlen_lo | (nlen_hi << 8)
         ok = ((prev & 0xE0) == 0) & (length == (~nlen & 0xFFFF))
         for i in np.nonzero(ok)[0]:
-            p = pos + int(i)
-            off = 8 * p - 3
-            if start_bit <= off < end_bit:
-                yield off
+            yield 8 * (pos + int(i)) - 3
         pos = hi
 
 
@@ -256,28 +256,42 @@ def scan_stored_candidates(
 # ---------------------------------------------------------------------------
 
 class CombinedBlockFinder:
-    """Merged Dynamic + Non-Compressed candidate stream for one chunk."""
+    """Merged Dynamic + Non-Compressed candidate stream for one chunk: the
+    ascending union of both finders' candidates in ``[start_bit, end_bit)``.
+
+    The cheap byte scan for stored blocks runs ahead and bounds the bit scan
+    for dynamic ones: the dynamic finder only looks below the next stored
+    candidate, and past it only when asked for another candidate. In a run
+    of stored blocks it then evaluates a block's worth of bits, not the
+    chunk's. ``dynamic_bits`` counts the bit offsets it evaluated.
+    """
 
     def __init__(self, data, start_bit: int, end_bit: int, *, stats: Optional[FilterStats] = None):
-        self._dyn = scan_dynamic_candidates(data, start_bit, end_bit, stats=stats)
-        self._ncb = scan_stored_candidates(data, start_bit, end_bit)
-        self._dyn_next = next(self._dyn, None)
-        self._ncb_next = next(self._ncb, None)
+        self.dynamic_bits = 0
+        self._it = self._merge(data, start_bit, end_bit, stats)
 
     def __iter__(self) -> "CombinedBlockFinder":
         return self
 
     def __next__(self) -> int:
-        d, s = self._dyn_next, self._ncb_next
-        if d is None and s is None:
-            raise StopIteration
-        if s is None or (d is not None and d <= s):
-            self._dyn_next = next(self._dyn, None)
-            if s is not None and d == s:  # dedupe identical offsets
-                self._ncb_next = next(self._ncb, None)
-            return d
-        self._ncb_next = next(self._ncb, None)
-        return s
+        return next(self._it)
+
+    def _merge(self, data, start_bit: int, end_bit: int, stats: Optional[FilterStats]) -> Iterator[int]:
+        dyn_end = min(end_bit, len(data) * 8 - _HEADER_PROBE_BITS)
+        pos = start_bit
+        for s in itertools.chain(scan_stored_candidates(data, start_bit, end_bit), [None]):
+            bound = dyn_end if s is None else min(s, dyn_end)
+            while pos < bound:
+                # One batch at a time, so the bits counted are the bits evaluated.
+                hi = min(pos + _DYNAMIC_BATCH_BITS, bound)
+                self.dynamic_bits += hi - pos
+                yield from scan_dynamic_candidates(data, pos, hi, stats=stats)
+                pos = hi
+            if s is None:
+                return
+            yield s
+            # A dynamic candidate at ``s`` itself would be the same offset.
+            pos = s + 1
 
 
 # ---------------------------------------------------------------------------

@@ -587,6 +587,7 @@ class ChunkFetcher:
         result: Optional[DecodeResult] = None
         sp = _task_span.get() if _obs_trace.tracing_enabled() else None
         find_s = 0.0
+        find_bits = 0
         trials = 0
         for (buf, base), at_eof in self._margins(start_bit // 8, stop_bit // 8):
             trial = self._decode(
@@ -597,6 +598,7 @@ class ChunkFetcher:
             failed.update(trial.failed)
             trials += trial.trials
             find_s += trial.find_s
+            find_bits += trial.find_bits
             with self._lock:
                 self.stats.candidates_tried += trial.trials
                 self.stats.false_positive_starts += len(trial.failed)
@@ -605,6 +607,7 @@ class ChunkFetcher:
                 break
         if sp is not None:
             sp.set_attr("find_s", find_s)
+            sp.set_attr("find_bits", find_bits)
             sp.set_attr("trials", trials)
 
         with self._lock:

@@ -331,7 +331,7 @@ class DeflateCodec(Codec):
     def find_chunk_starts(self, buf, start_bit: int, stop_bit: int) -> Iterator[int]:
         from .block_finder import CombinedBlockFinder
 
-        return iter(CombinedBlockFinder(buf, start_bit, stop_bit))
+        return CombinedBlockFinder(buf, start_bit, stop_bit)
 
     def decode_chunk(
         self,

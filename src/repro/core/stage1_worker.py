@@ -21,7 +21,7 @@ import os
 import time
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass, field
-from typing import Callable, Iterable, List, Optional
+from typing import Callable, Iterable, Iterator, List, Optional
 
 from .codec import CODECS, Codec, resolve_codec
 from .deflate import DecodeResult
@@ -40,6 +40,8 @@ class Trial:
     trials: int = 0
     #: wall time inside the block finder (only when asked to clock it)
     find_s: float = 0.0
+    #: bit offsets the finder's dynamic-header scan evaluated
+    find_bits: int = 0
 
 
 def trial_decode(
@@ -68,9 +70,8 @@ def trial_decode(
     skip = set(failed)
     out = Trial(result=None, need_more_data=False)
     spent = [0.0]
-    args = (buf, start_bit - base_bits, local_stop)
-    cands = (_clocked(spent, codec.find_chunk_starts, *args) if clock
-             else codec.find_chunk_starts(*args))
+    finder = codec.find_chunk_starts(buf, start_bit - base_bits, local_stop)
+    cands = _clocked(spent, finder) if clock else finder
     for cand in cands:
         if cand + base_bits in skip:
             continue
@@ -92,6 +93,7 @@ def trial_decode(
         skip.add(cand + base_bits)
         out.failed.append(cand + base_bits)
     out.find_s = spent[0]
+    out.find_bits = getattr(finder, "dynamic_bits", 0)
     return out
 
 
@@ -170,12 +172,10 @@ def start_pool(max_workers: int) -> Optional[ProcessPoolExecutor]:
 # -- helpers ------------------------------------------------------------------
 
 
-def _clocked(spent: List[float], make, *args):
-    """Iterate ``make(*args)``, adding the wall time spent inside it (the
-    call and every step, not the consumer's work between steps) to
-    ``spent[0]``."""
+def _clocked(spent: List[float], it: Iterator[int]):
+    """Iterate ``it``, adding the wall time spent inside each step (not the
+    consumer's work between steps) to ``spent[0]``."""
     t0 = time.perf_counter()
-    it = iter(make(*args))
     while True:
         try:
             item = next(it)

@@ -12,7 +12,7 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 import pytest
 
-from conftest import gzip_bytes, make_base64
+from conftest import gzip_bytes, make_base64, make_random
 from repro.core.chunk_fetcher import ChunkFetcher
 from repro.core.codec import DeflateCodec
 from repro.core.errors import GzipHeaderError
@@ -80,6 +80,7 @@ def test_traced_cold_scan_records_the_first_pass_from_inside(corpus):
     for s in nominal:
         assert s["attrs"]["trials"] >= (1 if s["attrs"]["bytes"] else 0)
         assert 0 <= s["attrs"]["find_s"] <= s["dur_s"]
+        assert 0 <= s["attrs"]["find_bits"]
 
     waits = by_name(spans, "fetcher.chunk_wait")
     assert waits
@@ -95,6 +96,27 @@ def test_traced_cold_scan_records_the_first_pass_from_inside(corpus):
     # The children lie inside their parents.
     inside = sum(s["dur_s"] for s in waits + stage2)
     assert inside <= sum(s["dur_s"] for s in frontier.values())
+
+
+def test_nominal_tasks_over_stored_blocks_scan_few_bits():
+    """In a run of stored blocks the dynamic-header scan stops at the first
+    stored block of each chunk, a small part of the chunk's bits."""
+    data = make_random(np.random.default_rng(0x18), 1 << 20)
+    comp = gzip_bytes(data, 6)
+    chunk = 256 << 10
+    obs_trace.enable_tracing(1 << 16)
+    pool = ThreadPoolExecutor(max_workers=2)
+    try:
+        with ParallelGzipReader(comp, parallelization=2, chunk_size=chunk, executor=pool) as r:
+            assert r.pread(0, 1 << 30) == data
+    finally:
+        pool.shutdown(wait=True)
+    nominal = [s["attrs"] for s in by_name(obs_trace.recorded_spans(), "fetcher.task")
+               if s["attrs"]["kind"] == "nom"]
+    assert sum(a["bytes"] > 0 for a in nominal) >= 3
+    chunk_bits = chunk * 8
+    for a in nominal:
+        assert 0 <= a["find_bits"] < chunk_bits // 8
 
 
 def test_served_cold_scan_traces_tasks_under_the_executor(corpus, tmp_path):
@@ -182,7 +204,7 @@ def task(kind, dur, cpu, nbytes, **attrs):
 
 
 SYNTHETIC = [
-    task("nom", 2.0, 0.5, 3_000_000, native_bytes=2_500_000),
+    task("nom", 2.0, 0.5, 3_000_000, native_bytes=2_500_000, find_s=0.5),
     task("fp", 1.0, 0.5, 1_000_000, native_bytes=500_000),
     task("ix", 5.0, 5.0, 9_000_000),  # indexed reads are not stage 1
     {"name": "reader.frontier_wait", "dur_s": 4.0, "attrs": {}},
@@ -199,6 +221,7 @@ SYNTHETIC = [
     ("frontier_stage1_wait_share.scan", 30.0),      # 3 s of a 10 s window
     ("frontier_stage2_wait_share.scan", 7.5),       # 0.75 s of 10 s
     ("stage1_native_share.scan", 75.0),             # zlib decoded 3 of 4 MB
+    ("stage1_find_share.scan", 25.0),               # 0.5 s of a 2 s nominal task
 ])
 def test_first_pass_metric_readers(metric, expected):
     from types import SimpleNamespace
