@@ -15,10 +15,12 @@ Orchestrates parallel chunk decompression:
     recorded (bit offset, window) — delegated to zlib where possible (paper
     §1.3: >2x faster than two-stage), falling back to the custom decoder for
     chunks containing gzip member boundaries. Where every chunk is a whole
-    member with its own trailer (BGZF), a task inflates a run of them
-    (``task_points``) and checks each one's ISIZE and CRC32, the CRCs in one
-    request to the stage-2 resolver, before any byte is cached; a cold read
-    whose run is not on the way gets its member alone first.
+    member with its own checksum and size (BGZF members and their trailers,
+    zstd frames and their seek-table entries), a task inflates a run of
+    them (``task_points``) and checks each one's size and checksum (CRC32,
+    or XXH64's low 32 bits), the checksums in one request to the stage-2
+    resolver, before any byte is cached; a cold read whose run is not on
+    the way gets its member alone first.
   * **Finalization** — window propagation is the only sequential step (last
     32 KiB per chunk); full marker replacement and CRC parts run on the pool
     (paper §2.2's Amdahl mitigation).
@@ -49,7 +51,7 @@ import numpy as np
 
 from ..obs import trace as _obs_trace
 from .cache import LRUCache
-from .codec import Codec, resolve_codec
+from .codec import Codec, frame_checksum, resolve_codec
 from .deflate import DecodeResult
 from .errors import (
     BlockNotFoundError,
@@ -96,8 +98,10 @@ class FetcherStats:
     stage1_offloaded: int = 0  # nominal and exact tasks decoded in the stage-1 pool
     stage1_native_bytes: int = 0  # of their results' bytes, those zlib decoded
     members_verified: int = 0  # trailer members whose CRC32 and ISIZE were checked
-    member_crc_mismatches: int = 0  # trailer members whose CRC32 or ISIZE did not match
-    member_crc_device_bytes: int = 0  # bytes of those members CRC'd on the device
+    frames_verified: int = 0  # zstd frames whose XXH64 was checked against the seek table
+    frames_unchecked: int = 0  # zstd frames whose seek table holds no checksum
+    member_crc_mismatches: int = 0  # members or frames whose checksum or size did not match
+    member_crc_device_bytes: int = 0  # bytes of those members checksummed on the device
 
     def as_dict(self) -> dict:
         return {k: int(getattr(self, k)) for k in self.__dataclass_fields__}
@@ -712,6 +716,16 @@ class ChunkFetcher:
             return self.resolver.crc32_many(datas)
         return [_zlib.crc32(d) & 0xFFFFFFFF for d in datas], False
 
+    def member_checksums(self, datas) -> Tuple[List[int], bool]:
+        """The codec's member checksum of each of ``datas`` in one resolver
+        request: CRC32, or the low 32 bits of XXH64 (libzstd without a
+        resolver); returns them and whether the device made them."""
+        if self.codec.member_checksum == "crc32":
+            return self.crc32_many(datas)
+        if self.resolver is not None:
+            return self.resolver.xxh64_many(datas)
+        return [frame_checksum(d) for d in datas], False
+
     # ------------------------------------------------------------------
     # indexed mode (second pass / imported index / BGZF)
     # ------------------------------------------------------------------
@@ -732,6 +746,8 @@ class ChunkFetcher:
                 return 1
             framed = len(self.index) > 0 and self.index.point_at(0).is_stream_start
             trailers = bool(self.codec.member_trailers and framed)
+            if trailers:
+                self.codec.prepare_members(self.reader)
             points = self._members_per_task() if trailers else 1
             with self._strategy_lock:
                 if trailers and not self._strategy_given:
@@ -742,9 +758,9 @@ class ChunkFetcher:
         return self._task_points
 
     def _members_per_task(self) -> int:
-        """Members one task inflates and verifies in one CRC request: as
-        many as let the ``parallelization`` tasks a sequential reader keeps
-        in flight fill one of the resolver's CRC batches
+        """Members one task inflates and verifies in one checksum request:
+        as many as let the ``parallelization`` tasks a sequential reader
+        keeps in flight fill one of the resolver's checksum batches
         (``max_batch_crc_bytes``) together, counting each member at the
         codec's largest. One, the unit of random access, without a resolver
         that batches."""
@@ -905,54 +921,73 @@ class ChunkFetcher:
         return self._verified_members(range(i, i + 1), ("ixm", i))
 
     def _verified_members(self, members: range, key) -> np.ndarray:
-        """Inflate ``members`` and check each one's trailer before any of its
-        bytes is cached (under ``key``) or served: ISIZE against the inflated
-        length, and (under ``verify``) CRC32 through the resolver, all the
-        members in one request. A mismatch raises GzipFooterError."""
+        """Inflate ``members`` and check each one before any of its bytes is
+        cached (under ``key``) or served: its size against the one its
+        format records (ISIZE, ``Decompressed_Size``), and (under
+        ``verify``) its checksum, all the members in one request
+        (``_checksum_mismatches``). A mismatch raises ``codec.member_error``.
+        A member whose format records no checksum (a zstd seek table without
+        ``Checksum_Flag``) counts in ``frames_unchecked``."""
+        codec = self.codec
         starts = [self.index.point_at(i).compressed_bit // 8 for i in members]
         stop = (self.index.point_at(members.stop).compressed_bit // 8
                 if members.stop < len(self.index) else self.file_size)
         buf, base = self._buffer(starts[0], stop)
         bounds = [b - base for b in starts] + [stop - base]
         bodies: List[bytes] = []
-        trailers: List[int] = []
+        wants: List[Optional[int]] = []
         try:
             for k, i in enumerate(members):
                 size = self.index.chunk_output_size(i)
-                body, crc, isize = self.codec.inflate_member(buf, bounds[k], bounds[k + 1], size)
-                if not len(body) == isize == size:
-                    raise GzipFooterError(
-                        "ISIZE mismatch in the member at decompressed offset %d"
+                body, want, recorded = codec.inflate_member(buf, bounds[k], bounds[k + 1], size, starts[k])
+                if not len(body) == recorded == size:
+                    raise codec.member_error(
+                        "size mismatch in the member at decompressed offset %d"
                         % self.index.point_at(i).decompressed_byte)
                 bodies.append(body)
-                trailers.append(crc)
-        except GzipFooterError:
+                wants.append(want)
+        except codec.member_error:
             with self._lock:
                 self.stats.member_crc_mismatches += 1
             raise
         nbytes = sum(len(b) for b in bodies)
+        checked = [k for k, want in enumerate(wants) if want is not None]
         with self._lock:
             self.stats.zlib_delegations += len(bodies)
-        if self.verify:
-            with _obs_trace.span("fetcher.member_verify", {"members": len(bodies), "bytes": nbytes}):
-                crcs, on_device = self.crc32_many(bodies)
-            bad = [k for k, (got, want) in enumerate(zip(crcs, trailers)) if got != want]
-            with self._lock:
-                self.stats.members_verified += len(bodies)
-                self.stats.member_crc_mismatches += len(bad)
-                if on_device:
-                    self.stats.member_crc_device_bytes += nbytes
+            self.stats.frames_unchecked += len(bodies) - len(checked)
+        if self.verify and checked:
+            bad = self._checksum_mismatches([bodies[k] for k in checked], [wants[k] for k in checked])
             if bad:
-                raise GzipFooterError(
-                    "CRC32 mismatch in the member at decompressed offset %d"
-                    % self.index.point_at(members[bad[0]]).decompressed_byte)
+                raise codec.member_error(
+                    "%s mismatch in the member at decompressed offset %d"
+                    % (codec.member_checksum.upper(),
+                       self.index.point_at(members[checked[bad[0]]]).decompressed_byte))
         data = np.frombuffer(b"".join(bodies), np.uint8)
         with self._lock:
             # Every inflate counts, a re-decode of an evicted task too, as
-            # every inflate's CRC is computed again.
+            # every inflate's checksum is computed again.
             self.stats.bytes_decompressed += nbytes
         self._insert_hinted(self.prefetch_cache, key, data, recompute_cost=nbytes)
         return data
+
+    def _checksum_mismatches(self, bodies: List[bytes], wants: List[int]) -> List[int]:
+        """Checksum ``bodies`` in one request and return the positions whose
+        checksum is not the one their format records (``wants``)."""
+        kind = self.codec.member_checksum
+        nbytes = sum(len(b) for b in bodies)
+        with _obs_trace.span("fetcher.member_verify",
+                             {"members": len(bodies), "bytes": nbytes, "checksum": kind}):
+            got, on_device = self.member_checksums(bodies)
+        bad = [k for k, (g, w) in enumerate(zip(got, wants)) if g != w]
+        with self._lock:
+            if kind == "crc32":
+                self.stats.members_verified += len(bodies)
+            else:
+                self.stats.frames_verified += len(bodies)
+            self.stats.member_crc_mismatches += len(bad)
+            if on_device:
+                self.stats.member_crc_device_bytes += nbytes
+        return bad
 
     # ------------------------------------------------------------------
 

@@ -20,8 +20,10 @@ exercise its opposite corners:
     member it is plain deflate, so decode/delegate are inherited.
   * ``ZstdCodec`` — the format-native case (ACEAPEX direction). The zstd
     seekable format's seek-table footer enumerates independent frames with
-    exact compressed+decompressed sizes; frames map 1:1 onto index chunks,
-    ``window_size`` is 0, and decoding is always a native-library call.
+    exact compressed+decompressed sizes and, where ``Checksum_Flag`` is set,
+    the low 32 bits of each frame's XXH64; frames map 1:1 onto index chunks,
+    ``window_size`` is 0, decoding is always a native-library call, and each
+    frame is checked against its entry before it is served.
 
 ## The codec contract
 
@@ -63,14 +65,20 @@ A ``Codec`` must provide:
     Seek-point flag mask for which ``delegate`` is invalid and
     ``decode_chunk`` must be used (deflate: interior member ends, shift-
     broken stored blocks).
-``member_trailers`` / ``inflate_member(buf, start, stop, size)``
+``member_trailers`` / ``inflate_member(buf, start, stop, size, offset)``
     True where every chunk of the exact index (points flagged
-    ``FLAG_STREAM_START``) is one whole gzip member ending in its own CRC32
-    and ISIZE (BGZF). The fetcher then inflates such chunks with
-    ``inflate_member``, which returns the body and the trailer's two
-    fields, and checks both before the bytes are cached or served.
-    ``max_member_bytes`` bounds what one such member inflates to; the
-    fetcher sizes its runs of members by it.
+    ``FLAG_STREAM_START``) is one whole member with its own checksum and
+    size: a gzip member ending in its CRC32 and ISIZE (BGZF), or a zstd
+    frame and its seek-table entry. The fetcher then inflates such chunks
+    with ``inflate_member`` (``offset``: the member's byte offset in the
+    file), which returns the body, the expected checksum (None where the
+    format holds none) and the expected size, and checks both before the
+    bytes are cached or served, raising ``member_error``.
+    ``member_checksum`` names the checksum (``"crc32"``, ``"xxh64"``: the
+    low 32 bits of XXH64, seed 0). ``prepare_members(reader)`` runs once
+    the index is final and before the first member is inflated; it sets
+    ``max_member_bytes``, which bounds what one member inflates to and by
+    which the fetcher sizes its runs of members.
 ``propagate_window(data, window)`` / ``replace_markers(data, window)``
     Stage-2 marker machinery; windowless codecs inherit the no-op defaults.
 ``set_stage2_resolver(resolver)``
@@ -136,7 +144,7 @@ from .deflate import (
     DeflateChunkDecoder,
     canonical_stored_offset,
 )
-from .errors import DeflateError, FormatError, GzipFooterError, GzipHeaderError
+from .errors import DeflateError, FormatError, GzipFooterError, GzipHeaderError, ZstdFrameError
 from .gzip_format import parse_gzip_header, scan_bgzf_members
 from .index import (
     FLAG_STORED_BLOCK,
@@ -163,8 +171,11 @@ class Codec:
     verifies_members: bool = False
     #: seek-point flags that force decode_chunk over delegate
     decoder_required_flags: int = 0
-    #: every index chunk is a whole member with its own CRC32/ISIZE trailer
+    #: every index chunk is a whole member with its own checksum and size
     member_trailers: bool = False
+    #: the checksum a member carries, and what a mismatch raises
+    member_checksum: str = "crc32"
+    member_error = GzipFooterError
     #: the most bytes such a member inflates to
     max_member_bytes: int = 0
     #: optional stage-2 resolver (duck-typed: ``replace_markers``/``crc32``,
@@ -191,6 +202,10 @@ class Codec:
 
     def build_exact_index(self, reader, index: GzipIndex) -> bool:
         return False
+
+    def prepare_members(self, reader) -> None:
+        """Read what checking members needs beyond the members themselves
+        (nothing for BGZF, whose trailers are inside)."""
 
     # -- speculative first pass --------------------------------------------
 
@@ -486,20 +501,22 @@ class BgzfCodec(DeflateCodec):
         index.finalize(out, reader.size())
         return True
 
-    def inflate_member(self, buf, start: int, stop: int, size: int) -> Tuple[bytes, int, int]:
-        """Inflate the member body at byte ``start`` of ``buf``, which ends
-        with its trailer before ``stop``; returns the body and the trailer's
-        CRC32 and ISIZE. A body longer than ``size`` bytes is an ISIZE
-        mismatch, found after inflating one byte more than ``size``."""
+    def inflate_member(self, buf, start: int, stop: int, size: int,
+                       offset: int) -> Tuple[bytes, int, int]:
+        """Inflate the member body at byte ``start`` of ``buf`` (``offset``
+        of the file), which ends with its trailer before ``stop``; returns
+        the body and the trailer's CRC32 and ISIZE. A body longer than
+        ``size`` bytes is an ISIZE mismatch, found after inflating one byte
+        more than ``size``."""
         d = zlib.decompressobj(-zlib.MAX_WBITS)
         try:
             body = d.decompress(memoryview(buf)[start:stop], size + 1)
         except zlib.error as exc:
-            raise DeflateError("BGZF member at byte %d: %s" % (start, exc)) from exc
+            raise DeflateError("BGZF member at byte %d: %s" % (offset, exc)) from exc
         if len(body) > size:
-            raise GzipFooterError("ISIZE mismatch: member at byte %d inflates past %d bytes" % (start, size))
+            raise GzipFooterError("ISIZE mismatch: member at byte %d inflates past %d bytes" % (offset, size))
         if not d.eof or len(d.unused_data) < 8:
-            raise DeflateError("BGZF member at byte %d is truncated" % start)
+            raise DeflateError("BGZF member at byte %d is truncated" % offset)
         crc, isize = struct.unpack("<II", d.unused_data[:8])
         return body, crc, isize
 
@@ -521,6 +538,7 @@ _ZSTD_SKIPPABLE_MIN = 0x184D2A50
 _ZSTD_SKIPPABLE_MAX = 0x184D2A5F
 _ZSTD_SEEKABLE_SKIPPABLE = 0x184D2A5E  # seek-table skippable frame magic
 _ZSTD_SEEKABLE_MAGIC = 0x8F92EAB1  # last 4 bytes of a seekable file
+_ZSTD_FASTEST_LEVEL = -(1 << 17)  # libzstd's ZSTD_minCLevel()
 
 
 def zstd_backend():
@@ -529,7 +547,9 @@ def zstd_backend():
     Prefers the stdlib ``compression.zstd`` (Python 3.14+), falls back to
     the optional ``zstandard`` package. Both expose ``ZstdCompressor`` /
     ``ZstdDecompressor`` with compatible one-shot APIs; the returned shim
-    normalizes the two call signatures.
+    normalizes the two call signatures. Its ``checksum(data)`` is the low 32
+    bits of XXH64 (seed 0) of ``data``, from libzstd: a frame compressed at
+    the fastest level with a content checksum ends in it.
     """
     try:
         from compression import zstd as _stdlib_zstd  # type: ignore
@@ -546,6 +566,15 @@ def zstd_backend():
                 # One frame only: trailing bytes beyond it are ignored.
                 d = _stdlib_zstd.ZstdDecompressor()
                 return d.decompress(data)
+
+            @staticmethod
+            def checksum(data: bytes) -> int:
+                param = _stdlib_zstd.CompressionParameter
+                frame = _stdlib_zstd.compress(data, options={
+                    param.compression_level: param.compression_level.bounds()[0],
+                    param.checksum_flag: 1,
+                })
+                return int.from_bytes(frame[-4:], "little")
 
         return _StdlibShim
     except ImportError:
@@ -566,6 +595,13 @@ def zstd_backend():
                 # trailing bytes from the next frame in the same buffer.
                 return _zstandard.ZstdDecompressor().decompressobj().decompress(data)
 
+            @staticmethod
+            def checksum(data: bytes) -> int:
+                frame = _zstandard.ZstdCompressor(
+                    level=_ZSTD_FASTEST_LEVEL, write_checksum=True, write_content_size=False
+                ).compress(data)
+                return int.from_bytes(frame[-4:], "little")
+
         return _ZstandardShim
     except ImportError:
         return None
@@ -575,13 +611,25 @@ def have_zstd() -> bool:
     return zstd_backend() is not None
 
 
-def parse_zstd_seek_table(reader) -> List[Tuple[int, int, int]]:
-    """[(frame_byte_offset, compressed_size, decompressed_size), ...].
+def frame_checksum(data) -> int:
+    """The low 32 bits of XXH64 (seed 0) of ``data``, as a zstd frame's
+    ``Content_Checksum`` and a seek-table entry's ``Checksum`` hold them,
+    computed by libzstd."""
+    backend = zstd_backend()
+    if backend is None:
+        raise FormatError("a zstd frame checksum needs a zstd library")
+    return backend.checksum(bytes(data))
+
+
+def parse_zstd_seek_table(reader) -> List[Tuple[int, int, int, Optional[int]]]:
+    """[(frame_byte_offset, compressed_size, decompressed_size, checksum), ...].
 
     Parses the seekable-format footer: the file's final skippable frame
     carries N ``(compressed_size, decompressed_size[, checksum])`` entries
     followed by ``(frame_count: u32, descriptor: u8, 0x8F92EAB1: u32)``.
-    Raises FormatError when the footer is absent or inconsistent.
+    ``checksum`` is the low 32 bits of the frame's XXH64, or None where the
+    descriptor's ``Checksum_Flag`` (bit 7) is clear. Raises FormatError when
+    the footer is absent or inconsistent.
     """
     size = reader.size()
     if size < 17:  # skippable header (8) + footer (9)
@@ -592,7 +640,8 @@ def parse_zstd_seek_table(reader) -> List[Tuple[int, int, int]]:
         raise FormatError("zstd source has no seekable seek table")
     if descriptor & 0x7C:  # reserved bits must be zero
         raise FormatError("zstd seek table has reserved descriptor bits set")
-    entry_size = 12 if descriptor & 0x80 else 8
+    checksums = bool(descriptor & 0x80)
+    entry_size = 12 if checksums else 8
     payload = n_frames * entry_size + 9
     table_start = size - payload - 8
     if table_start < 0:
@@ -604,11 +653,12 @@ def parse_zstd_seek_table(reader) -> List[Tuple[int, int, int]]:
     entries_raw = reader.pread(table_start + 8, n_frames * entry_size)
     if len(entries_raw) != n_frames * entry_size:
         raise FormatError("truncated zstd seek table")
-    frames: List[Tuple[int, int, int]] = []
+    frames: List[Tuple[int, int, int, Optional[int]]] = []
     comp_off = 0
     for i in range(n_frames):
         comp_size, dec_size = struct.unpack_from("<II", entries_raw, i * entry_size)
-        frames.append((comp_off, comp_size, dec_size))
+        checksum = struct.unpack_from("<I", entries_raw, i * entry_size + 8)[0] if checksums else None
+        frames.append((comp_off, comp_size, dec_size, checksum))
         comp_off += comp_size
     if comp_off != table_start:
         raise FormatError(
@@ -623,7 +673,12 @@ class ZstdCodec(Codec):
 
     Opposite corner of the interface from deflate: no speculation, no
     markers, ``window_size == 0`` (frames are independent), every chunk
-    decoded by one native-library call. Requires ``compression.zstd``
+    decoded by one native-library call. Every frame is a checked member
+    (``member_trailers``): its size against the entry's
+    ``Decompressed_Size``, and, where the table carries checksums, the low
+    32 bits of its XXH64 against the entry's ``Checksum``. The library
+    checks only the checksum a frame carries itself (``Content_Checksum``),
+    which seekable archives need not write. Requires ``compression.zstd``
     (3.14+) or the optional ``zstandard`` package at decode time; ``probe``
     works without either.
     """
@@ -631,7 +686,15 @@ class ZstdCodec(Codec):
     tag = "zstd"
     window_size = 0
     supports_speculation = False
-    verifies_members = False  # the library verifies per-frame checksums
+    # No first pass: frames are checked by the fetcher as members.
+    verifies_members = False
+    member_trailers = True
+    member_checksum = "xxh64"
+    member_error = ZstdFrameError
+
+    def __init__(self):
+        #: frame byte offset -> (compressed size, decompressed size, checksum)
+        self._frames: Optional[dict] = None
 
     def probe(self, head: bytes) -> bool:
         if len(head) < 4:
@@ -650,11 +713,16 @@ class ZstdCodec(Codec):
             )
         return backend
 
+    def _read_table(self, reader) -> List[Tuple[int, int, int, Optional[int]]]:
+        frames = parse_zstd_seek_table(reader)
+        self.max_member_bytes = max((dec for _, _, dec, _ in frames), default=0)
+        self._frames = {off: (comp, dec, checksum) for off, comp, dec, checksum in frames}
+        return frames
+
     def build_exact_index(self, reader, index: GzipIndex) -> bool:
         self._backend()  # fail early with a clear error, before any decode
-        frames = parse_zstd_seek_table(reader)
         out = 0
-        for comp_off, comp_size, dec_size in frames:
+        for comp_off, comp_size, dec_size, _ in self._read_table(reader):
             if dec_size == 0:
                 continue  # skippable or empty frame: nothing addressable
             index.add_point(SeekPoint(comp_off * 8, out, b"", FLAG_STREAM_START))
@@ -662,54 +730,22 @@ class ZstdCodec(Codec):
         index.finalize(out, reader.size())
         return True
 
-    def decode_chunk(
-        self,
-        buf,
-        start_bit: int,
-        stop_bit: Optional[int] = None,
-        *,
-        window: Optional[bytes] = None,
-        max_out: Optional[int] = None,
-    ) -> DecodeResult:
-        if start_bit % 8:
-            raise FormatError("zstd frames are byte-aligned")
-        stop_byte = len(buf) if stop_bit is None else (stop_bit + 7) // 8
-        raw = self.delegate_bytes(buf, start_bit // 8, stop_byte)
-        if max_out is not None and len(raw) > max_out:
-            raise FormatError("zstd frame output exceeds max_out=%d" % max_out)
-        data = np.frombuffer(raw, dtype=np.uint8)
-        res = DecodeResult(
-            start_bit=start_bit,
-            end_bit=stop_byte * 8,
-            data=data,
-            marker_mode=False,
-        )
-        res.ended_at_eos = stop_byte >= len(buf)
-        return res
+    def prepare_members(self, reader) -> None:
+        # An imported index skipped build_exact_index: read the table here.
+        if self._frames is None:
+            self._read_table(reader)
 
-    def delegate(
-        self,
-        buf,
-        start_bit: int,
-        window: bytes,
-        out_size: int,
-        *,
-        max_input_bytes: Optional[int] = None,
-    ) -> bytes:
-        if start_bit % 8:
-            raise FormatError("zstd frames are byte-aligned")
-        start = start_bit // 8
-        stop = len(buf) if max_input_bytes is None else min(len(buf), start + max_input_bytes)
-        raw = self.delegate_bytes(buf, start, stop)
-        if len(raw) < out_size:
-            raise FormatError(
-                "zstd frame produced %d of %d bytes" % (len(raw), out_size)
-            )
-        return raw[:out_size]
-
-    def delegate_bytes(self, buf, start_byte: int, stop_byte: int) -> bytes:
-        backend = self._backend()
-        return backend.decompress_frame(bytes(buf[start_byte:stop_byte]))
+    def inflate_member(self, buf, start: int, stop: int, size: int,
+                       offset: int) -> Tuple[bytes, Optional[int], int]:
+        """Decompress the frame at byte ``start`` of ``buf`` (``offset`` of
+        the file); returns its bytes and its seek-table entry's checksum
+        (None without ``Checksum_Flag``) and ``Decompressed_Size``."""
+        entry = self._frames.get(offset)
+        if entry is None:
+            raise ZstdFrameError("no seek-table entry for a frame at byte %d" % offset)
+        comp, dec, checksum = entry
+        body = self._backend().decompress_frame(bytes(buf[start : min(stop, start + comp)]))
+        return body, checksum, dec
 
 
 # ---------------------------------------------------------------------------

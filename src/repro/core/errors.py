@@ -21,6 +21,11 @@ class GzipFooterError(FormatError):
     """CRC32 or ISIZE mismatch in a gzip member footer."""
 
 
+class ZstdFrameError(FormatError):
+    """A zstd frame disagrees with its seek-table entry: the low 32 bits of
+    its XXH64, or its decompressed size."""
+
+
 class BlockNotFoundError(RapidgzipError):
     """No deflate block candidate could be confirmed inside a chunk."""
 

@@ -26,6 +26,8 @@ import struct
 import zlib
 from typing import Iterable, List
 
+from .codec import frame_checksum, zstd_backend
+
 _GZIP_HEADER = b"\x1f\x8b\x08\x00\x00\x00\x00\x00\x00\xff"
 
 
@@ -155,8 +157,9 @@ def zstd_seekable_compress(data: bytes, level: int = 3, frame_size: int = 128 <<
     """Zstd seekable format: independent frames + the seek-table footer.
 
     The footer is the final skippable frame (magic 0x184D2A5E) holding one
-    ``(compressed_size, decompressed_size)`` u32 pair per frame, then
-    ``(frame_count, descriptor, 0x8F92EAB1)``. Needs a zstd library for the
+    ``(compressed_size, decompressed_size, checksum)`` u32 triple per frame,
+    then ``(frame_count, descriptor, 0x8F92EAB1)`` with ``Checksum_Flag``
+    set. Needs a zstd library for the
     frame bodies (``core.codec.have_zstd``) — raises RuntimeError without
     one, so callers gate on availability rather than silently degrading.
     """
@@ -177,14 +180,15 @@ class ZstdSeekableStreamWriter:
 
     Buffers decompressed input up to ``frame_size``, emits each chunk as an
     independent zstd frame, and :meth:`finish` appends the seek-table
-    skippable frame (magic 0x184D2A5E, 8-byte entries, no checksums) that
-    ``core.codec.parse_zstd_seek_table`` reads back. Needs a zstd library
+    skippable frame (magic 0x184D2A5E, ``Checksum_Flag`` set, 12-byte
+    entries) that ``core.codec.parse_zstd_seek_table`` reads back. Each
+    entry's checksum is the low 32 bits of its frame's XXH64, from libzstd
+    (``core.codec.frame_checksum``), so a reader checks every frame of the
+    twin before serving it. Needs a zstd library
     (``core.codec.have_zstd``) — raises RuntimeError without one.
     """
 
     def __init__(self, sink, level: int = 3, frame_size: int = 128 << 10):
-        from .codec import zstd_backend
-
         self._backend = zstd_backend()
         if self._backend is None:
             raise RuntimeError("ZstdSeekableStreamWriter needs a zstd library")
@@ -220,7 +224,7 @@ class ZstdSeekableStreamWriter:
         frame = self._backend.compress(block, self._level)
         self._sink.write(frame)
         self.bytes_out += len(frame)
-        self._entries.append(struct.pack("<II", len(frame), len(block)))
+        self._entries.append(struct.pack("<III", len(frame), len(block), frame_checksum(block)))
 
     def finish(self) -> None:
         if self._finished:
@@ -230,7 +234,7 @@ class ZstdSeekableStreamWriter:
             self._emit(bytes(self._buf))
             self._buf.clear()
         table = b"".join(self._entries) + struct.pack(
-            "<IBI", len(self._entries), 0, 0x8F92EAB1
+            "<IBI", len(self._entries), 0x80, 0x8F92EAB1
         )
         skippable = struct.pack("<II", 0x184D2A5E, len(table)) + table
         self._sink.write(skippable)

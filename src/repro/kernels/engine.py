@@ -8,10 +8,13 @@ chunk; CODAG and Sitaridi et al. (PAPERS.md) both show that decompression
 on wide-SIMD hardware lives or dies on amortizing exactly those costs.
 
 This engine is the process-wide aggregation point: every reader/tenant
-submits marker-resolution and CRC requests here; a single dispatcher thread
-packs them into fixed-size tile batches, dispatches the batched Pallas
-kernels (``marker_replace_tiles_multi`` / ``crc32_segments_batched``) once
-per batch, and scatters results back to per-request futures.
+submits marker-resolution, CRC32 and XXH64 requests here; a single
+dispatcher thread packs them into fixed-size batches, dispatches the batched
+kernels (``marker_replace_tiles_multi`` / ``crc32_segments_batched`` /
+``xxh64_stripes``) once per batch, and scatters results back to per-request
+futures. An XXH64 request holds the frames of a seekable-zstd task, whose
+digests the fetcher checks against the seek table; it batches like a CRC
+request, under the same byte cap.
 
 Layout and policy:
 
@@ -25,14 +28,17 @@ Layout and policy:
     bounded set of shapes once and are reused forever (cached compiled
     kernels). A CRC batch lays all its parts into the lanes of one row
     (``crc32.pack_parts``), so its shape is ``seg_len`` alone, a power of
-    two: whatever the number of requests, one shape per size class.
+    two: whatever the number of requests, one shape per size class. An
+    XXH64 batch gives each frame a row (``xxh64.pack_frames``): its shape
+    is the frames and the stripes, each a power of two.
   * **Double-buffered staging** — two host staging buffers per bucket shape
     alternate between dispatches, and result readback of batch N overlaps
     the launch of batch N+1 (one dispatch in flight), so host packing and
     device compute pipeline instead of serializing.
   * **Routing** — without ``force_device`` every request is resolved on
-    the CPU inline (``core.markers`` / ``zlib.crc32``) and counted in
-    ``fallbacks``; with it, every request goes to the device. A size
+    the CPU inline (``core.markers`` / ``zlib.crc32`` / libzstd's frame
+    checksum, ``core.codec.frame_checksum``) and counted in ``fallbacks``;
+    with it, every request goes to the device. A size
     threshold measured on the device belongs in ``_route_device``, the one
     place the rule lives.
   * **One device** — every array the engine dispatches is placed on
@@ -57,6 +63,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from ..core.codec import frame_checksum as _cpu_frame_checksum
 from ..core.markers import replace_markers as _cpu_replace_markers
 from ..obs import trace as _obs_trace
 from .crc32 import (
@@ -75,6 +82,7 @@ from .marker_replace import (
 )
 from .ops import interpret_on
 from .ref import make_replacement_table
+from .xxh64 import finish_frames, pack_frames, stripes_bucket, xxh64_rows
 
 
 class EngineClosedError(RuntimeError):
@@ -90,7 +98,8 @@ def _pow2_at_least(n: int, cap: Optional[int] = None) -> int:
 
 class _Request:
     """One queued request: a marker stream, or the byte strings (``parts``)
-    whose CRC32s one future resolves to (a list, or an int for ``crc32``)."""
+    whose CRC32s or XXH64s one future resolves to (a list, or an int for
+    ``crc32``)."""
 
     __slots__ = ("kind", "symbols", "window", "parts", "many", "tiles", "nbytes", "future")
 
@@ -119,8 +128,10 @@ class DeviceDecodeEngine:
       * ``replace_markers(symbols, window) -> np.uint8 ndarray`` (blocking)
       * ``crc32(data) -> int`` and ``crc32_many(datas) -> (crcs, on_device)``
         (blocking)
-      * ``submit_replace`` / ``submit_crc`` / ``submit_crcs`` -> Future
-        (async variants)
+      * ``xxh64_many(datas) -> (digests, on_device)`` (blocking): the low 32
+        bits of each one's XXH64, as zstd frames and seek tables hold them
+      * ``submit_replace`` / ``submit_crc`` / ``submit_crcs`` /
+        ``submit_xxh64s`` -> Future (async variants)
       * ``stats() -> dict`` / ``shutdown()``
     """
 
@@ -148,6 +159,7 @@ class DeviceDecodeEngine:
         self._cond = threading.Condition()
         self._rq: Deque[_Request] = deque()
         self._cq: Deque[_Request] = deque()
+        self._xq: Deque[_Request] = deque()
         self._closed = False
         # Replacement tables are pure functions of the window; serving reads
         # hit the same windows repeatedly (re-reads, overlapping ranges), so
@@ -166,13 +178,14 @@ class DeviceDecodeEngine:
         self._staging: Dict[Tuple, List[np.ndarray]] = {}
 
         # Counters (mutated under self._cond).
-        self._requests = {"replace": 0, "crc": 0}
-        self._fallbacks = {"replace": 0, "crc": 0}
+        self._requests = {"replace": 0, "crc": 0, "xxh64": 0}
+        self._fallbacks = {"replace": 0, "crc": 0, "xxh64": 0}
         self._batches = 0
         self._dispatches = 0
         self._batched_requests = 0
         self._tiles_dispatched = 0
         self._tiles_padded = 0
+        # Bytes checksummed on the device, any checksum (CRC32, XXH64).
         self._crc_bytes = 0
         self._max_queue_depth = 0
         self._errors = 0
@@ -233,12 +246,24 @@ class DeviceDecodeEngine:
         self._enqueue(self._cq, req)
         return req.future
 
+    def submit_xxh64s(self, datas: Sequence) -> Future:
+        """Queue one request for the low 32 bits of the XXH64 (seed 0) of
+        each of ``datas``; resolves to the list of digests. The frames
+        dispatch together, with those of other requests queued beside them."""
+        self._count(self._requests, "xxh64")
+        req = _Request("xxh64", parts=[_as_bytes(d) for d in datas])
+        if not req.parts:
+            req.future.set_result([])
+            return req.future
+        self._enqueue(self._xq, req)
+        return req.future
+
     def _enqueue(self, queue: Deque[_Request], req: _Request) -> None:
         with self._cond:
             if self._closed:
                 raise EngineClosedError("DeviceDecodeEngine is shut down")
             queue.append(req)
-            depth = len(self._rq) + len(self._cq)
+            depth = len(self._rq) + len(self._cq) + len(self._xq)
             if depth > self._max_queue_depth:
                 self._max_queue_depth = depth
             self._cond.notify_all()
@@ -288,23 +313,62 @@ class DeviceDecodeEngine:
         self._count(self._fallbacks, "crc")
         return [_zlib.crc32(d) & 0xFFFFFFFF for d in datas], False
 
+    def xxh64_many(self, datas: Sequence) -> Tuple[List[int], bool]:
+        """The low 32 bits of the XXH64 of each of ``datas`` as one request,
+        routed like ``crc32``: batched on the device, or libzstd's frame
+        checksum on the CPU; returns the digests and whether the device
+        computed them."""
+        datas = [_as_bytes(d) for d in datas]
+        if self._route_device():
+            try:
+                fut = self.submit_xxh64s(datas)
+                with _obs_trace.timed("engine.batch_wait",
+                                      {"kind": "xxh64", "nbytes": sum(len(d) for d in datas)}):
+                    return fut.result(), True
+            except EngineClosedError:
+                pass
+        else:
+            self._count(self._requests, "xxh64")
+        self._count(self._fallbacks, "xxh64")
+        return [_cpu_frame_checksum(d) for d in datas], False
+
     # ------------------------------------------------------------------
     # dispatcher thread
     # ------------------------------------------------------------------
 
-    def _crc_full(self) -> bool:
-        """The queued CRC requests fill a batch: as many requests as one may
-        hold, every lane taken, or no room for one more like the largest."""
-        if not self._cq:
+    def _full(self, queue: Deque[_Request], max_parts: Optional[int]) -> bool:
+        """The queued checksum requests fill a batch: as many requests as one
+        may hold, ``max_parts`` parts (every CRC lane) taken, or no room for
+        one more like the largest."""
+        if not queue:
             return False
         return (
-            len(self._cq) >= self.max_crc_requests
-            or sum(len(r.parts) for r in self._cq) >= N_SEGMENTS
-            or sum(r.nbytes for r in self._cq) + max(r.nbytes for r in self._cq)
+            len(queue) >= self.max_crc_requests
+            or (max_parts is not None and sum(len(r.parts) for r in queue) >= max_parts)
+            or sum(r.nbytes for r in queue) + max(r.nbytes for r in queue)
             > self.max_batch_crc_bytes
         )
 
-    def _collect_batch(self) -> Optional[Tuple[List[_Request], List[_Request], float]]:
+    def _take(self, queue: Deque[_Request], max_parts: Optional[int]) -> List[_Request]:
+        """Pop the checksum requests of one batch off ``queue``: at least
+        one, then while they fit the request, part and byte caps."""
+        out: List[_Request] = []
+        parts = nbytes = 0
+        while queue:
+            req = queue[0]
+            if out and (
+                len(out) >= self.max_crc_requests
+                or (max_parts is not None and parts + len(req.parts) > max_parts)
+                or nbytes + req.nbytes > self.max_batch_crc_bytes
+            ):
+                break
+            queue.popleft()
+            out.append(req)
+            parts += len(req.parts)
+            nbytes += req.nbytes
+        return out
+
+    def _collect_batch(self) -> Optional[Tuple[List[_Request], List[_Request], List[_Request], float]]:
         """Block until work (or shutdown); return one coalesced batch and the
         seconds spent waiting for it to fill.
 
@@ -313,7 +377,7 @@ class DeviceDecodeEngine:
         coalesces into one dispatch. Returns None at shutdown.
         """
         with self._cond:
-            while not self._closed and not self._rq and not self._cq:
+            while not self._closed and not self._rq and not self._cq and not self._xq:
                 self._cond.wait()
             if self._closed:
                 return None
@@ -322,7 +386,8 @@ class DeviceDecodeEngine:
                 deadline = t_fill + self.max_delay_s
                 while not self._closed:
                     tiles = sum(r.tiles for r in self._rq)
-                    if tiles >= self.max_batch_tiles or self._crc_full():
+                    if (tiles >= self.max_batch_tiles or self._full(self._cq, N_SEGMENTS)
+                            or self._full(self._xq, None)):
                         break
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
@@ -348,21 +413,7 @@ class DeviceDecodeEngine:
                 rep.append(req)
                 tiles += req.tiles
                 tables.add(key)
-            crc: List[_Request] = []
-            crc_parts = crc_bytes = 0
-            while self._cq:
-                req = self._cq[0]
-                if crc and (
-                    len(crc) >= self.max_crc_requests
-                    or crc_parts + len(req.parts) > N_SEGMENTS
-                    or crc_bytes + req.nbytes > self.max_batch_crc_bytes
-                ):
-                    break
-                self._cq.popleft()
-                crc.append(req)
-                crc_parts += len(req.parts)
-                crc_bytes += req.nbytes
-            return rep, crc, fill_s
+            return rep, self._take(self._cq, N_SEGMENTS), self._take(self._xq, None), fill_s
 
     def _worker_loop(self) -> None:
         pending = None  # resolve-callback of the previous (in-flight) batch
@@ -370,17 +421,19 @@ class DeviceDecodeEngine:
             batch = self._collect_batch()
             if batch is None:
                 break
-            rep, crc, fill_s = batch
+            rep, crc, xxh, fill_s = batch
             launched = []
             try:
                 if rep:
                     launched.append(self._dispatch_replace(rep))
                 if crc:
                     launched.append(self._dispatch_crc(crc, fill_s))
+                if xxh:
+                    launched.append(self._dispatch_xxh64(xxh, fill_s))
             except BaseException as exc:  # noqa: BLE001 - fail the batch, keep serving
                 with self._cond:
                     self._errors += 1
-                _fail(rep + crc, exc)
+                _fail(rep + crc + xxh, exc)
                 continue
             # Pipeline: resolve the *previous* dispatch only after launching
             # this one — readback of batch N overlaps device work of N+1.
@@ -389,10 +442,10 @@ class DeviceDecodeEngine:
             if launched:
                 with self._cond:
                     self._batches += 1
-                    self._batched_requests += len(rep) + len(crc)
+                    self._batched_requests += len(rep) + len(crc) + len(xxh)
             pending = launched or None
             with self._cond:
-                idle = not self._rq and not self._cq
+                idle = not self._rq and not self._cq and not self._xq
             if idle and pending is not None:
                 self._resolve_safely(pending)
                 pending = None
@@ -588,6 +641,56 @@ class DeviceDecodeEngine:
 
         return resolve, reqs
 
+    # -- XXH64 dispatch --------------------------------------------------
+
+    def _dispatch_xxh64(self, reqs: List[_Request], fill_s: float = 0.0):
+        """Pack every frame of every request into a row of its own of one
+        (frames, stripes * 8) dispatch, both powers of two.
+
+        Returns ``(resolve, reqs)`` like ``_dispatch_crc``, with the same
+        ``engine.dispatch`` / ``engine.resolve`` spans under tracing;
+        ``resolve`` finishes each frame's digest on the host
+        (``xxh64.finish_frames``).
+        """
+        tracing = _obs_trace.tracing_enabled()
+        t0 = time.perf_counter()
+        c0 = time.thread_time() if tracing else 0.0
+        parts = [p for r in reqs for p in r.parts]
+        nbytes = sum(len(p) for p in parts)
+        shape = (_pow2_at_least(len(parts)), 8 * stripes_bucket([len(p) for p in parts]))
+        stage = self._staging_buffer(("xxh64",) + shape, shape)
+        counts = pack_frames(stage, parts)
+        t1 = time.perf_counter()
+        out = xxh64_rows(jax.device_put(stage, self.device), jax.device_put(counts, self.device),
+                         interpret=self.interpret)
+        with self._cond:
+            self._dispatches += 1
+            self._crc_bytes += nbytes
+        if tracing:
+            t2 = time.perf_counter()
+            _obs_trace.record_span("engine.dispatch", t0, t2 - t0, {
+                "kind": "xxh64", "requests": len(reqs), "parts": len(parts),
+                "bytes": nbytes, "rows": shape[0], "fill_s": fill_s,
+                "pack_s": t1 - t0, "launch_s": t2 - t1, "cpu_s": time.thread_time() - c0})
+
+        def resolve() -> None:
+            r0 = time.perf_counter()
+            rc0 = time.thread_time() if tracing else 0.0
+            acc = np.asarray(out)
+            r1 = time.perf_counter()
+            digests = iter(finish_frames(acc, parts))
+            for req in reqs:
+                got = [next(digests) & 0xFFFFFFFF for _ in req.parts]
+                if not req.future.done():
+                    req.future.set_result(got)
+            if tracing:
+                r2 = time.perf_counter()
+                _obs_trace.record_span("engine.resolve", r0, r2 - r0, {
+                    "kind": "xxh64", "requests": len(reqs), "readback_s": r1 - r0,
+                    "fold_s": r2 - r1, "cpu_s": time.thread_time() - rc0})
+
+        return resolve, reqs
+
     # ------------------------------------------------------------------
     # lifecycle & telemetry
     # ------------------------------------------------------------------
@@ -607,9 +710,10 @@ class DeviceDecodeEngine:
         if self._worker is not None:
             self._worker.join(timeout=30)
         with self._cond:
-            leftovers = list(self._rq) + list(self._cq)
+            leftovers = list(self._rq) + list(self._cq) + list(self._xq)
             self._rq.clear()
             self._cq.clear()
+            self._xq.clear()
         for req in leftovers:
             if not req.future.done():
                 req.future.set_exception(
@@ -641,7 +745,7 @@ class DeviceDecodeEngine:
                     self._tiles_dispatched / tiles_total if tiles_total else 0.0
                 ),
                 "crc_bytes": self._crc_bytes,
-                "queue_depth": len(self._rq) + len(self._cq),
+                "queue_depth": len(self._rq) + len(self._cq) + len(self._xq),
                 "max_queue_depth": self._max_queue_depth,
                 "errors": self._errors,
                 "closed": self._closed,

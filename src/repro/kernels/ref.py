@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (allclose targets in tests)."""
+"""Plain references for every device kernel: pure-jnp oracles (allclose
+targets in tests), and XXH64 in Python integers."""
 
 from __future__ import annotations
 
@@ -104,3 +105,74 @@ def crc32_segments_batched_ref(data: jax.Array) -> jax.Array:
     init = jnp.full((data.shape[0],) + data.shape[2:], jnp.int32(-1))
     crc, _ = jax.lax.scan(step, init, jnp.moveaxis(data, 1, 0))
     return ~crc
+
+
+# -- xxh64 --------------------------------------------------------------------
+# XXH64 as its specification writes it (github.com/Cyan4973/xxHash,
+# doc/xxhash_spec.md), in Python integers masked to 64 bits.
+
+XXH_P1 = 0x9E3779B185EBCA87
+XXH_P2 = 0xC2B2AE3D27D4EB4F
+XXH_P3 = 0x165667B19E3779F9
+XXH_P4 = 0x85EBCA77C2B2AE63
+XXH_P5 = 0x27D4EB2F165667C5
+_M64 = (1 << 64) - 1
+
+
+def _rotl64(x: int, r: int) -> int:
+    return ((x << r) | (x >> (64 - r))) & _M64
+
+
+def xxh64_round(acc: int, lane: int) -> int:
+    """One accumulator step over one little-endian 8-byte word."""
+    return _rotl64((acc + lane * XXH_P2) & _M64, 31) * XXH_P1 & _M64
+
+
+def xxh64_init(seed: int = 0) -> list:
+    """The four accumulators before the first 32-byte stripe."""
+    return [(seed + XXH_P1 + XXH_P2) & _M64, (seed + XXH_P2) & _M64,
+            seed & _M64, (seed - XXH_P1) & _M64]
+
+
+def xxh64_finish(acc: list, data: bytes, seed: int = 0) -> int:
+    """XXH64 of ``data`` from its four accumulators after its
+    ``len(data) // 32`` whole stripes: the merge, the length, the tail of
+    fewer than 32 bytes (8, 4, then 1 at a time) and the avalanche."""
+    n = len(data)
+    p = n - n % 32
+    if n >= 32:
+        h = (_rotl64(acc[0], 1) + _rotl64(acc[1], 7) + _rotl64(acc[2], 12)
+             + _rotl64(acc[3], 18)) & _M64
+        for v in acc:
+            h = ((h ^ xxh64_round(0, v)) * XXH_P1 + XXH_P4) & _M64
+    else:
+        h = (seed + XXH_P5) & _M64
+    h = (h + n) & _M64
+    while p + 8 <= n:
+        h ^= xxh64_round(0, int.from_bytes(data[p : p + 8], "little"))
+        h = (_rotl64(h, 27) * XXH_P1 + XXH_P4) & _M64
+        p += 8
+    if p + 4 <= n:
+        h ^= int.from_bytes(data[p : p + 4], "little") * XXH_P1 & _M64
+        h = (_rotl64(h, 23) * XXH_P2 + XXH_P3) & _M64
+        p += 4
+    while p < n:
+        h ^= data[p] * XXH_P5 & _M64
+        h = _rotl64(h, 11) * XXH_P1 & _M64
+        p += 1
+    h ^= h >> 33
+    h = h * XXH_P2 & _M64
+    h ^= h >> 29
+    h = h * XXH_P3 & _M64
+    return h ^ (h >> 32)
+
+
+def xxh64(data: bytes, seed: int = 0) -> int:
+    """XXH64 of ``data``: every whole 32-byte stripe steps the four
+    accumulators, one 8-byte word each, then ``xxh64_finish``."""
+    data = bytes(data)
+    acc = xxh64_init(seed)
+    for p in range(0, len(data) - len(data) % 32, 32):
+        for j in range(4):
+            acc[j] = xxh64_round(acc[j], int.from_bytes(data[p + 8 * j : p + 8 * j + 8], "little"))
+    return xxh64_finish(acc, data, seed)

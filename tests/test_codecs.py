@@ -201,12 +201,16 @@ def test_detect_codec_tag_malformed_source_degrades():
 # ---------------------------------------------------------------------------
 
 
-def _fake_seekable(frames):
+def _fake_seekable(frames, checksums=None):
     """Seekable container with arbitrary (fake) frame bytes — the seek-table
-    parser never decodes frames, so it is testable without a zstd library."""
+    parser never decodes frames, so it is testable without a zstd library.
+    With ``checksums`` the entries are 12 bytes and ``Checksum_Flag`` is set."""
     body = b"".join(f for f, _ in frames)
-    entries = b"".join(struct.pack("<II", len(f), d) for f, d in frames)
-    table = entries + struct.pack("<IBI", len(frames), 0, 0x8F92EAB1)
+    if checksums is None:
+        entries = b"".join(struct.pack("<II", len(f), d) for f, d in frames)
+    else:
+        entries = b"".join(struct.pack("<III", len(f), d, c) for (f, d), c in zip(frames, checksums))
+    table = entries + struct.pack("<IBI", len(frames), 0 if checksums is None else 0x80, 0x8F92EAB1)
     return body + struct.pack("<II", 0x184D2A5E, len(table)) + table
 
 
@@ -214,7 +218,10 @@ def test_zstd_seek_table_parses_without_library():
     frames = [(b"\x28\xb5\x2f\xfdAAAA", 100), (b"\x28\xb5\x2f\xfdBBBBBB", 250)]
     blob = _fake_seekable(frames)
     got = parse_zstd_seek_table(BytesFileReader(blob))
-    assert got == [(0, 8, 100), (8, 10, 250)]
+    assert got == [(0, 8, 100, None), (8, 10, 250, None)]
+    blob = _fake_seekable(frames, checksums=[0xDEADBEEF, 7])
+    got = parse_zstd_seek_table(BytesFileReader(blob))
+    assert got == [(0, 8, 100, 0xDEADBEEF), (8, 10, 250, 7)]
 
 
 def test_zstd_seek_table_rejects_inconsistent_footer():

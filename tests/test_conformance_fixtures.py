@@ -11,6 +11,7 @@ import os
 import pytest
 
 from repro.core.codec import detect_codec, have_zstd, parse_zstd_seek_table
+from repro.kernels.ref import xxh64
 from repro.core.filereader import BytesFileReader
 from repro.core.gzip_format import scan_bgzf_members
 from repro.core.reader import ParallelGzipReader
@@ -73,10 +74,15 @@ def test_zstd_seek_table_parses_without_backend(payload):
     assert len(frames) == 3
     assert frames[0][0] == 0
     # offsets chain: each frame starts where the previous ended
-    for (off_a, comp_a, _), (off_b, _, _) in zip(frames, frames[1:]):
+    for (off_a, comp_a, _, _), (off_b, _, _, _) in zip(frames, frames[1:]):
         assert off_b == off_a + comp_a
-    assert sum(dec for _, _, dec in frames) == len(payload)
-    assert all(dec <= 32 << 10 for _, _, dec in frames)
+    assert sum(dec for _, _, dec, _ in frames) == len(payload)
+    assert all(dec <= 32 << 10 for _, _, dec, _ in frames)
+    # Checksum_Flag is set: each entry holds its frame's XXH64 low 32 bits.
+    off = 0
+    for _, _, dec, checksum in frames:
+        assert checksum == xxh64(payload[off : off + dec]) & 0xFFFFFFFF
+        off += dec
 
 
 @pytest.mark.zstd

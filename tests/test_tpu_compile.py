@@ -22,6 +22,7 @@ from repro.kernels.engine import DeviceDecodeEngine
 from repro.kernels.marker_replace import TILE_COLS, TILE_ROWS, marker_replace_tiles_multi
 from repro.kernels.precode_check import BLOCK, ROWS, precode_check_blocks
 from repro.kernels.ref import TABLE_SIZE
+from repro.kernels.xxh64 import BLOCK_BYTES, LANES, xxh64_rows
 
 pytestmark = pytest.mark.kernels
 
@@ -116,3 +117,22 @@ def test_precode_check_compiles_for_v5e(one_chip):
     block = ROWS * BLOCK * 4
     assert 3 * 2 * block <= V5E_SCOPED_VMEM_BYTES
     assert compiled.memory_analysis().output_size_in_bytes == n_rows * BLOCK * 4
+
+
+@pytest.mark.parametrize("frames, stripes", [
+    (4, 1 << 15),  # a full batch of 1 MiB frames: four rows of 32,768 stripes
+    (1, 16),  # the smallest bucket: one frame, one 128-word vreg row
+    (256, 1 << 9),  # a batch of 16 KiB frames
+])
+def test_xxh64_compiles_for_v5e(one_chip, frames, stripes):
+    # The engine gives each frame a row of its own; the kernel reads the
+    # rows as uploaded, with no transposing program around it.
+    compiled = _compile(
+        lambda r, n: xxh64_rows(r, n, interpret=False), one_chip, (frames, 8 * stripes), (frames,)
+    )
+    assert _is_pallas(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes <= frames * LANES * 4 * 2
+    # Double-buffered input block plus the stripe counts and the resident
+    # accumulators.
+    block = min(8 * stripes * frames * 4, max(LANES * frames * 4, BLOCK_BYTES))
+    assert 2 * block + 3 * frames * LANES * 4 <= V5E_SCOPED_VMEM_BYTES
